@@ -49,7 +49,7 @@ def bench_block_sparse(p: float = 0.8, block: int = 16):
     w = jnp.where(jnp.asarray(mask), w, 0)
     bm = block_mask_from_weight_mask(mask, 128, 128)
     counts, idx = plan_blocks(bm)
-    y = blocksparse_matmul(x, w, counts, idx, interpret=True)
+    y = blocksparse_matmul(x, w, counts, idx)
     yref = block_sparse_matmul_ref(x, w, jnp.asarray(bm), 128, 128)
     err = float(jnp.abs(y - yref).max())
     return {"skip_frac": skipped / max(total, 1), "allclose_err": err,
@@ -75,8 +75,8 @@ def bench_quant_sparse(block: int = 16, K: int = 256, N: int = 256):
     p = pack_projection(w, block, quant="int8")
     wfq = jnp.asarray(dequantized_weight(p, K))
     x = jax.random.normal(kx, (64, K), jnp.float32)
-    y_q = sparse_linear(x, wfq, p, interpret=True, quant="int8")
-    y_ref = sparse_linear(x, wfq, p, interpret=True, quant="none")
+    y_q = sparse_linear(x, wfq, p, quant="int8")
+    y_ref = sparse_linear(x, wfq, p, quant="none")
     dense = x @ w
     tile_bytes = int(p.tiles.size)                    # int8: 1 B/elem
     scale_bytes = int(p.scales.size) * 4

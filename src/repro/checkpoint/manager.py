@@ -41,7 +41,11 @@ class CheckpointManager:
         (async checkpointing: train continues while IO happens)."""
         host = {(_path_key(p)): np.asarray(jax.device_get(leaf))
                 for p, leaf in iter_paths(tree)}
-        meta = {"step": step, "time": time.time(), **(extra_meta or {})}
+        # npz stores bfloat16 as raw 2-byte records; the dtype names let
+        # restore view them back
+        meta = {"step": step, "time": time.time(),
+                "dtypes": {k: v.dtype.name for k, v in host.items()},
+                **(extra_meta or {})}
         self.wait()
         if blocking:
             self._write(step, host, meta)
@@ -99,9 +103,13 @@ class CheckpointManager:
             raise FileNotFoundError(f"no checkpoint in {self.directory}")
         path = os.path.join(self.directory, f"step_{step:08d}", "arrays.npz")
         data = np.load(path)
+        dtypes = self.meta(step).get("dtypes", {})
         tree = like
         for p, leaf in iter_paths(like):
-            arr = data[_path_key(p)]
+            key = _path_key(p)
+            arr = data[key]
+            if arr.dtype.kind == "V":
+                arr = arr.view(jax.numpy.dtype(dtypes[key]))
             if shardings is not None:
                 shard = shardings
                 for k in p:

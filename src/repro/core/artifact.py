@@ -60,7 +60,9 @@ class PrunedArtifact:
         mgr.save(0, self.params, blocking=True,
                  extra_meta={"kind": "pruned_artifact",
                              "arch": self.recipe.arch,
-                             "category": self.report.get("category")})
+                             "category": self.report.get("category"),
+                             "param_dtype": jax.numpy.dtype(
+                                 self.params["embed"]["table"].dtype).name})
         mgr.save_json(RECIPE_FILE, self.recipe.to_dict())
         mgr.save_json(CONFIG_FILE, config_to_dict(self.cfg))
         mgr.save_json(TARGETS_FILE,
@@ -91,9 +93,12 @@ class PrunedArtifact:
         mgr = CheckpointManager(directory, keep=1)
         recipe = PruneRecipe.from_dict(mgr.load_json(RECIPE_FILE))
         cfg = config_from_dict(mgr.load_json(CONFIG_FILE))
-        # restore params into the exact tree the pruned config implies
+        # restore params into the exact tree (and dtype) the pruned
+        # config was saved with
+        dtype = mgr.meta().get("param_dtype", "float32")
         like = jax.eval_shape(
-            functools.partial(T.init_model, cfg=cfg), jax.random.PRNGKey(0))
+            functools.partial(T.init_model, cfg=cfg, dtype=dtype),
+            jax.random.PRNGKey(0))
         params = mgr.restore(like)
         targets = {(int(layer), name): float(t)
                    for layer, name, t in mgr.load_json(TARGETS_FILE)}

@@ -13,7 +13,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.common.compat import pvary, shard_map
+
+def _varying(x, axis: str):
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def pipeline_forward(stage_fn: Callable, stage_params, x_microbatches,
@@ -34,16 +36,15 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_microbatches,
         stage = jax.lax.axis_index(axis)
         mb_shape = xs.shape[1:]
         # mark buffers as stage-varying from the start (VMA-stable carry)
-        buf = pvary(jnp.zeros(mb_shape, xs.dtype), (axis,))
-        outs = pvary(jnp.zeros((n_micro,) + mb_shape, xs.dtype),
-                             (axis,))
+        buf = _varying(jnp.zeros(mb_shape, xs.dtype), axis)
+        outs = _varying(jnp.zeros((n_micro,) + mb_shape, xs.dtype), axis)
 
         def step(t, carry):
             buf, outs = carry
             # stage 0 ingests microbatch t (if any); others use the ring input
             feed = jax.lax.dynamic_index_in_dim(
                 xs, jnp.clip(t, 0, n_micro - 1), axis=0, keepdims=False)
-            inp = jnp.where(stage == 0, pvary(feed, (axis,)), buf)
+            inp = jnp.where(stage == 0, _varying(feed, axis), buf)
             out = stage_fn(params_local, inp)
             # final stage commits microbatch (t - n_stages + 1)
             commit = t - (n_stages - 1)
@@ -66,6 +67,6 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_microbatches,
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params,
                              is_leaf=lambda x: hasattr(x, "shape")), P())
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P())
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P())
     return fn(stage_params, x_microbatches)

@@ -1,3 +1,20 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the paper's compute hot spots.
+
+Whether a kernel runs compiled or through the Pallas interpreter is
+decided here, once, from the backend: the CPU backend interprets, every
+accelerator compiles. The serving and model paths never choose; a
+kernel op's explicit ``interpret`` argument exists for the equivalence
+tests.
+"""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """``interpret`` if given, else True exactly on the CPU backend."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() == "cpu"

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import counters
+from repro.kernels import counters, interpret_mode
 from repro.kernels.block_sparse.kernel import (block_sparse_matmul,
                                                quant_block_sparse_matmul)
 
@@ -92,11 +92,11 @@ def _blocksparse_matmul_jit(x, w, counts, indices, block_m, block_k,
 
 
 def blocksparse_matmul(x, w, counts, indices, block_m=128, block_k=128,
-                       block_n=128, interpret=False):
+                       block_n=128, interpret=None):
     """Public op: y = x @ w visiting nonzero weight blocks only."""
     counters.record("block_sparse")
     return _blocksparse_matmul_jit(x, w, counts, indices, block_m, block_k,
-                                   block_n, interpret)
+                                   block_n, interpret_mode(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_k", "block_n",
@@ -111,9 +111,9 @@ def _quant_blocksparse_matmul_jit(x, tiles, counts, indices, slots, scales,
 
 def quant_blocksparse_matmul(x, tiles, counts, indices, slots, scales,
                              block_m=128, block_k=128, block_n=128,
-                             interpret=False):
+                             interpret=None):
     """Public op: y = x @ w with kept tiles stored int8 + pow2 scales."""
     counters.record("block_sparse_quant")
     return _quant_blocksparse_matmul_jit(x, tiles, counts, indices, slots,
                                          scales, block_m, block_k, block_n,
-                                         interpret)
+                                         interpret_mode(interpret))

@@ -40,6 +40,13 @@ alongside the tile plan):
   M-tile which expert's plan and weights it runs. The grid is
   (M/bm, N-blocks, max_nnz) — proportional to tokens actually routed,
   not E·capacity.
+
+Every scalar-prefetched table is flattened to 1-D before the launch.
+SMEM pads the last dimension of a multi-dimensional array to 128
+words, so an ``(E, N/bn, max_nnz)`` index table with ``max_nnz`` 6
+costs as much as one with 128: at 128 experts that overflows the 1 MiB
+of SMEM. Flat, the tables cost their true size; the kernels address
+them with the row-major offsets below.
 """
 from __future__ import annotations
 
@@ -51,8 +58,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _flat(*arrays):
+    return tuple(a.reshape(-1) for a in arrays)
+
+
 def _kernel(count_ref, idx_ref, work_ref, x_ref, w_ref, o_ref, acc_ref, *,
-            max_nnz: int):
+            max_nnz: int, n_n: int, n_m: int):
     e = pl.program_id(0)
     m = pl.program_id(1)
     n = pl.program_id(2)
@@ -62,7 +73,7 @@ def _kernel(count_ref, idx_ref, work_ref, x_ref, w_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((s < count_ref[e, n]) & (work_ref[e, m] > 0))
+    @pl.when((s < count_ref[e * n_n + n]) & (work_ref[e * n_m + m] > 0))
     def _accum():
         acc_ref[...] += jnp.dot(x_ref[0], w_ref[0],
                                 preferred_element_type=jnp.float32)
@@ -100,16 +111,22 @@ def grouped_block_sparse_matmul(x: jax.Array, w: jax.Array,
         work = jnp.ones((E, M // block_m), jnp.int32)
     assert work.shape == (E, M // block_m)
 
-    def x_map(e, m, n, s, cnt, idx, wrk):
+    n_n, n_m = N // block_n, M // block_m
+
+    def k_block(e, m, n, s, idx, wrk):
         # dead (e, m)-blocks pin the K-block to the step-0 one so every
         # later step revisits it and the DMA is elided
-        return (e, m, jnp.where(wrk[e, m] > 0, idx[e, n, s], idx[e, n, 0]))
+        row = (e * n_n + n) * max_nnz
+        return jnp.where(wrk[e * n_m + m] > 0, idx[row + s], idx[row])
+
+    def x_map(e, m, n, s, cnt, idx, wrk):
+        return (e, m, k_block(e, m, n, s, idx, wrk))
 
     def w_map(e, m, n, s, cnt, idx, wrk):
-        return (e, jnp.where(wrk[e, m] > 0, idx[e, n, s], idx[e, n, 0]), n)
+        return (e, k_block(e, m, n, s, idx, wrk), n)
 
-    grid = (E, M // block_m, N // block_n, max_nnz)
-    kernel = functools.partial(_kernel, max_nnz=max_nnz)
+    grid = (E, n_m, n_n, max_nnz)
+    kernel = functools.partial(_kernel, max_nnz=max_nnz, n_n=n_n, n_m=n_m)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -126,11 +143,12 @@ def grouped_block_sparse_matmul(x: jax.Array, w: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
         interpret=interpret,
-    )(counts, indices, work, x, w)
+    )(*_flat(counts, indices, work), x, w)
 
 
 def _quant_kernel(count_ref, idx_ref, slot_ref, scale_ref, work_ref, x_ref,
-                  w_ref, o_ref, acc_ref, *, max_nnz: int):
+                  w_ref, o_ref, acc_ref, *, max_nnz: int, n_n: int,
+                  n_m: int):
     e = pl.program_id(0)
     m = pl.program_id(1)
     n = pl.program_id(2)
@@ -140,13 +158,13 @@ def _quant_kernel(count_ref, idx_ref, slot_ref, scale_ref, work_ref, x_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((s < count_ref[e, n]) & (work_ref[e, m] > 0))
+    @pl.when((s < count_ref[e * n_n + n]) & (work_ref[e * n_m + m] > 0))
     def _accum():
         # pow2 per-tile scale on the accumulated product: bitwise-equal
         # to the unquantized kernel over the fake-quant weight stack
         acc_ref[...] += jnp.dot(x_ref[0], w_ref[0].astype(x_ref.dtype),
                                 preferred_element_type=jnp.float32
-                                ) * scale_ref[e, n, s]
+                                ) * scale_ref[(e * n_n + n) * max_nnz + s]
 
     @pl.when(s == max_nnz - 1)
     def _flush():
@@ -182,14 +200,21 @@ def quant_grouped_block_sparse_matmul(x: jax.Array, tiles: jax.Array,
         work = jnp.ones((E, M // block_m), jnp.int32)
     assert work.shape == (E, M // block_m)
 
+    n_n, n_m = N // block_n, M // block_m
+
+    def step(table, e, m, n, s, wrk):
+        row = (e * n_n + n) * max_nnz
+        return jnp.where(wrk[e * n_m + m] > 0, table[row + s], table[row])
+
     def x_map(e, m, n, s, cnt, idx, slt, scl, wrk):
-        return (e, m, jnp.where(wrk[e, m] > 0, idx[e, n, s], idx[e, n, 0]))
+        return (e, m, step(idx, e, m, n, s, wrk))
 
     def w_map(e, m, n, s, cnt, idx, slt, scl, wrk):
-        return (jnp.where(wrk[e, m] > 0, slt[e, n, s], slt[e, n, 0]), 0, 0)
+        return (step(slt, e, m, n, s, wrk), 0, 0)
 
-    grid = (E, M // block_m, N // block_n, max_nnz)
-    kernel = functools.partial(_quant_kernel, max_nnz=max_nnz)
+    grid = (E, n_m, n_n, max_nnz)
+    kernel = functools.partial(_quant_kernel, max_nnz=max_nnz, n_n=n_n,
+                               n_m=n_m)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -206,11 +231,12 @@ def quant_grouped_block_sparse_matmul(x: jax.Array, tiles: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
         interpret=interpret,
-    )(counts, indices, slots, scales, work, x, tiles)
+    )(*_flat(counts, indices, slots, scales, work), x, tiles)
 
 
 def _quant_ragged_kernel(count_ref, idx_ref, slot_ref, scale_ref, tile_ref,
-                         x_ref, w_ref, o_ref, acc_ref, *, max_nnz: int):
+                         x_ref, w_ref, o_ref, acc_ref, *, max_nnz: int,
+                         n_n: int):
     t = pl.program_id(0)
     n = pl.program_id(1)
     s = pl.program_id(2)
@@ -221,11 +247,11 @@ def _quant_ragged_kernel(count_ref, idx_ref, slot_ref, scale_ref, tile_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((e >= 0) & (s < count_ref[ec, n]))
+    @pl.when((e >= 0) & (s < count_ref[ec * n_n + n]))
     def _accum():
         acc_ref[...] += jnp.dot(x_ref[...], w_ref[0].astype(x_ref.dtype),
                                 preferred_element_type=jnp.float32
-                                ) * scale_ref[ec, n, s]
+                                ) * scale_ref[(ec * n_n + n) * max_nnz + s]
 
     @pl.when(s == max_nnz - 1)
     def _flush():
@@ -250,16 +276,19 @@ def quant_ragged_block_sparse_matmul(x: jax.Array, tiles: jax.Array,
     assert tile_expert.shape == (M // block_m,)
     max_nnz = indices.shape[-1]
 
+    def step(table, t, n, s, te):
+        row = (jnp.maximum(te[t], 0) * nN + n) * max_nnz
+        return jnp.where(te[t] >= 0, table[row + s], table[row])
+
     def x_map(t, n, s, cnt, idx, slt, scl, te):
-        ec = jnp.maximum(te[t], 0)
-        return (t, jnp.where(te[t] >= 0, idx[ec, n, s], idx[ec, n, 0]))
+        return (t, step(idx, t, n, s, te))
 
     def w_map(t, n, s, cnt, idx, slt, scl, te):
-        ec = jnp.maximum(te[t], 0)
-        return (jnp.where(te[t] >= 0, slt[ec, n, s], slt[ec, n, 0]), 0, 0)
+        return (step(slt, t, n, s, te), 0, 0)
 
-    grid = (M // block_m, N // block_n, max_nnz)
-    kernel = functools.partial(_quant_ragged_kernel, max_nnz=max_nnz)
+    grid = (M // block_m, nN, max_nnz)
+    kernel = functools.partial(_quant_ragged_kernel, max_nnz=max_nnz,
+                               n_n=nN)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -276,11 +305,11 @@ def quant_ragged_block_sparse_matmul(x: jax.Array, tiles: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
-    )(counts, indices, slots, scales, tile_expert, x, tiles)
+    )(*_flat(counts, indices, slots, scales), tile_expert, x, tiles)
 
 
 def _ragged_kernel(count_ref, idx_ref, tile_ref, x_ref, w_ref, o_ref,
-                   acc_ref, *, max_nnz: int):
+                   acc_ref, *, max_nnz: int, n_n: int):
     t = pl.program_id(0)
     n = pl.program_id(1)
     s = pl.program_id(2)
@@ -291,7 +320,7 @@ def _ragged_kernel(count_ref, idx_ref, tile_ref, x_ref, w_ref, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((e >= 0) & (s < count_ref[ec, n]))
+    @pl.when((e >= 0) & (s < count_ref[ec * n_n + n]))
     def _accum():
         acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
                                 preferred_element_type=jnp.float32)
@@ -328,16 +357,20 @@ def ragged_block_sparse_matmul(x: jax.Array, w: jax.Array,
     assert tile_expert.shape == (M // block_m,)
     max_nnz = indices.shape[-1]
 
+    n_n = N // block_n
+
+    def k_block(t, n, s, idx, te):
+        row = (jnp.maximum(te[t], 0) * n_n + n) * max_nnz
+        return jnp.where(te[t] >= 0, idx[row + s], idx[row])
+
     def x_map(t, n, s, cnt, idx, te):
-        ec = jnp.maximum(te[t], 0)
-        return (t, jnp.where(te[t] >= 0, idx[ec, n, s], idx[ec, n, 0]))
+        return (t, k_block(t, n, s, idx, te))
 
     def w_map(t, n, s, cnt, idx, te):
-        ec = jnp.maximum(te[t], 0)
-        return (ec, jnp.where(te[t] >= 0, idx[ec, n, s], idx[ec, n, 0]), n)
+        return (jnp.maximum(te[t], 0), k_block(t, n, s, idx, te), n)
 
-    grid = (M // block_m, N // block_n, max_nnz)
-    kernel = functools.partial(_ragged_kernel, max_nnz=max_nnz)
+    grid = (M // block_m, n_n, max_nnz)
+    kernel = functools.partial(_ragged_kernel, max_nnz=max_nnz, n_n=n_n)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -353,4 +386,4 @@ def ragged_block_sparse_matmul(x: jax.Array, w: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
-    )(counts, indices, tile_expert, x, w)
+    )(*_flat(counts, indices), tile_expert, x, w)

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import counters
+from repro.kernels import counters, interpret_mode
 from repro.kernels.grouped_block_sparse.kernel import (
     grouped_block_sparse_matmul, quant_grouped_block_sparse_matmul,
     quant_ragged_block_sparse_matmul, ragged_block_sparse_matmul)
@@ -49,7 +49,7 @@ def _grouped_matmul_jit(x, w, counts, indices, work, block_m, block_k,
 
 
 def grouped_blocksparse_matmul(x, w, counts, indices, block_m=None,
-                               block_k=128, block_n=128, interpret=False,
+                               block_k=128, block_n=128, interpret=None,
                                row_live=None):
     """Public op: y[e] = x[e] @ w[e] for all experts in one launch,
     visiting nonzero weight blocks only. ``block_m=None`` keeps each
@@ -76,7 +76,7 @@ def grouped_blocksparse_matmul(x, w, counts, indices, block_m=None,
     counters.record_concrete("grouped_block_sparse_experts_computed",
                              experts_computed)
     return _grouped_matmul_jit(x, w, counts, indices, work, block_m,
-                               block_k, block_n, interpret)
+                               block_k, block_n, interpret_mode(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_k", "block_n",
@@ -100,7 +100,7 @@ def _quant_grouped_matmul_jit(x, tiles, counts, indices, slots, scales,
 
 def quant_grouped_blocksparse_matmul(x, tiles, counts, indices, slots,
                                      scales, block_m=None, block_k=128,
-                                     block_n=128, interpret=False,
+                                     block_n=128, interpret=None,
                                      row_live=None):
     """Public op: the grouped launch with kept tiles stored int8 + pow2
     scales (same panel default and ``row_live`` occupancy masking as
@@ -121,7 +121,7 @@ def quant_grouped_blocksparse_matmul(x, tiles, counts, indices, slots,
                              experts_computed)
     return _quant_grouped_matmul_jit(x, tiles, counts, indices, slots,
                                      scales, work, block_m, block_k,
-                                     block_n, interpret)
+                                     block_n, interpret_mode(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_k", "block_n",
@@ -138,7 +138,7 @@ def _quant_ragged_matmul_jit(x, tiles, counts, indices, slots, scales,
 def quant_ragged_blocksparse_matmul(x, tiles, counts, indices, slots,
                                     scales, tile_expert,
                                     block_m=RAGGED_BLOCK_ROWS, block_k=128,
-                                    block_n=128, interpret=False):
+                                    block_n=128, interpret=None):
     """Public op: the ragged routed-tokens-only launch with kept tiles
     stored int8 + pow2 scales."""
     counters.record("grouped_block_sparse_ragged_quant")
@@ -151,12 +151,13 @@ def quant_ragged_blocksparse_matmul(x, tiles, counts, indices, slots,
         "grouped_block_sparse_ragged_quant_experts_computed", occupied)
     return _quant_ragged_matmul_jit(x, tiles, counts, indices, slots,
                                     scales, tile_expert.astype(jnp.int32),
-                                    block_m, block_k, block_n, interpret)
+                                    block_m, block_k, block_n,
+                                    interpret_mode(interpret))
 
 
 def ragged_blocksparse_matmul(x, w, counts, indices, tile_expert,
                               block_m=RAGGED_BLOCK_ROWS, block_k=128,
-                              block_n=128, interpret=False):
+                              block_n=128, interpret=None):
     """Public op: the ragged expert batch (routed tokens packed into
     ``block_m``-aligned per-expert segments) through every owning
     expert's tile plan, one launch, M-grid sized by the packed buffer
@@ -172,4 +173,4 @@ def ragged_blocksparse_matmul(x, w, counts, indices, tile_expert,
                              occupied)
     return _ragged_matmul_jit(x, w, counts, indices,
                               tile_expert.astype(jnp.int32), block_m,
-                              block_k, block_n, interpret)
+                              block_k, block_n, interpret_mode(interpret))

@@ -15,6 +15,14 @@ softmax in (m, l, acc) VMEM scratch across the block axis. Blocks wholly
 past the sequence length are skipped with ``@pl.when`` (their index-map
 entry is clamped so the revisit-detection DMA elides the copy), and the
 tail block masks columns ``>= length`` to -inf before the running max.
+
+Layout: the TPU lowering wants the last two dimensions of every block
+to be (8, 128)-divisible or whole. The arena is viewed as
+``(n_blocks + 1, block_size, n_kv * D)`` — a free reshape of the
+contiguous ``(.., n_kv, D)`` tail — so one KV head's block is the
+``(block_size, D)`` tile at lane offset ``h * D`` (a strided DMA), and
+the query as ``(B, n_kv, group, D)`` so a head-group block spans its
+whole ``(group, D)`` tail. Neither depends on the GQA group size.
 """
 from __future__ import annotations
 
@@ -44,9 +52,9 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j * block_size < length)
     def _block():
-        q = q_ref[0].astype(jnp.float32) * scale       # (group, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)      # (bs, D)
+        q = q_ref[0, 0].astype(jnp.float32) * scale    # (group, D)
+        k = k_ref[0].astype(jnp.float32)               # (bs, D)
+        v = v_ref[0].astype(jnp.float32)               # (bs, D)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (group, bs)
 
         # length mask: decode attends to kv positions [0, length) only —
@@ -66,8 +74,8 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == max_blocks - 1)
     def _flush():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                    ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
@@ -96,29 +104,34 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
         # sequence's first block lets consecutive skipped steps reuse the
         # resident VMEM copy instead of DMA-ing dead blocks
         blk = jnp.where(j * block_size < lens[b], tbl[b, j], tbl[b, 0])
-        return (blk, 0, h, 0)
+        return (blk, 0, h)
 
+    def q_index(b, h, j, tbl, lens):
+        return (b, h, 0, 0)
+
+    n_rows = k_arena.shape[0]
+    k_view = k_arena.reshape(n_rows, block_size, n_kv * D)
+    v_view = v_arena.reshape(n_rows, block_size, n_kv * D)
     kernel = functools.partial(_kernel, scale=scale, block_size=block_size,
                                max_blocks=max_blocks)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, group, D),
-                             lambda b, h, j, tbl, lens: (b, h, 0)),
-                pl.BlockSpec((1, block_size, 1, D), kv_index),
-                pl.BlockSpec((1, block_size, 1, D), kv_index),
+                pl.BlockSpec((1, 1, group, D), q_index),
+                pl.BlockSpec((1, block_size, D), kv_index),
+                pl.BlockSpec((1, block_size, D), kv_index),
             ],
-            out_specs=pl.BlockSpec((1, group, D),
-                                   lambda b, h, j, tbl, lens: (b, h, 0)),
+            out_specs=pl.BlockSpec((1, 1, group, D), q_index),
             scratch_shapes=[
                 pltpu.VMEM((group, 1), jnp.float32),
                 pltpu.VMEM((group, 1), jnp.float32),
                 pltpu.VMEM((group, D), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, n_q, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, group, D), q.dtype),
         interpret=interpret,
-    )(block_tables, lengths, q, k_arena, v_arena)
+    )(block_tables, lengths, q.reshape(B, n_kv, group, D), k_view, v_view)
+    return out.reshape(B, n_q, D)

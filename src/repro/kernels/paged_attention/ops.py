@@ -6,15 +6,20 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import counters
+from repro.kernels import counters, interpret_mode
 from repro.kernels.paged_attention.kernel import paged_attention
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_decode(q, k_arena, v_arena, block_tables, lengths,
-                           interpret: bool = False):
+                           interpret=None):
     """q: (B, 1, Hq, D); k/v arena: (n_blocks + 1, bs, Hkv, D);
     block_tables: (B, max_blocks); lengths: (B,) -> (B, 1, Hq, D)."""
+    return _decode_jit(q, k_arena, v_arena, block_tables, lengths,
+                       interpret=interpret_mode(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _decode_jit(q, k_arena, v_arena, block_tables, lengths, interpret):
     counters.record("paged_attention")
     B, S, Hq, D = q.shape
     assert S == 1, f"paged_attention is decode-only (S=1), got S={S}"

@@ -35,6 +35,7 @@ from repro.configs.registry import get_config, get_smoke_config, list_archs
 from repro.core.pipeline import MosaicPipeline
 from repro.core.prune_controller import PLATFORMS
 from repro.core.recipe import CalibrationSpec, PruneRecipe
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 
 
@@ -68,6 +69,7 @@ def recipe_from_args(args: argparse.Namespace) -> PruneRecipe:
 
 
 def main() -> None:
+    enable_compile_cache()
     # surface INFO logs (e.g. pack_model's skipped-projection summary)
     logging.basicConfig(level=logging.INFO, format="%(message)s")
     ap = argparse.ArgumentParser()

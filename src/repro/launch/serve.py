@@ -54,11 +54,26 @@ from repro.core.artifact import PrunedArtifact
 from repro.core.pipeline import MosaicPipeline
 from repro.core.recipe import CalibrationSpec, PruneRecipe
 from repro.data.pipeline import SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve.batching import ContinuousEngine, latency_percentiles
 from repro.serve.config import ServeConfig
 from repro.serve.engine import Engine
 from repro.serve.scheduler import Request
+
+
+def _dtype(args):
+    """Smoke configs serve in float32; real widths take ServeConfig's
+    bfloat16 defaults for weights, compute and cache."""
+    return jnp.float32 if args.smoke else ServeConfig.compute_dtype
+
+
+def _block(args) -> int:
+    """Plan tile: the recipe's (128 by default, the size the chip's
+    block-sparse kernel tiles); smoke configs are too narrow for it."""
+    if args.sparse_block:
+        return args.sparse_block
+    return 16 if args.smoke else PruneRecipe.block
 
 
 def _load_or_prune(args) -> tuple:
@@ -77,7 +92,8 @@ def _load_or_prune(args) -> tuple:
         else:
             recipe = PruneRecipe(
                 arch=args.arch, p=args.prune, category=args.category,
-                align_channels=8, block=args.sparse_block,
+                align_channels=8 if args.smoke else _block(args),
+                block=_block(args),
                 quant=("int8" if args.quant == "int8" else "none"),
                 calibration=CalibrationSpec(n_samples=8, batch_size=4,
                                             seq_len=args.prompt_len))
@@ -95,7 +111,7 @@ def _load_or_prune(args) -> tuple:
         cfg = (get_smoke_config(recipe.arch) if args.smoke
                else get_config(recipe.arch))
         cfg = cfg.replace(scan_layers=False)
-        params = T.init_model(jax.random.PRNGKey(0), cfg)
+        params = T.init_model(jax.random.PRNGKey(0), cfg, _dtype(args))
         art = MosaicPipeline(recipe).run(params, cfg)
         if args.save_artifact:
             art.save(args.save_artifact)
@@ -108,13 +124,13 @@ def _load_or_prune(args) -> tuple:
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.replace(scan_layers=False)
-    params = T.init_model(jax.random.PRNGKey(0), cfg)
+    params = T.init_model(jax.random.PRNGKey(0), cfg, _dtype(args))
     packed = None
     if args.sparse:
         # unpruned weights have no zero tiles, but the kernel path is
         # still exercised (plans at ~100% density)
         from repro.serve.sparse import pack_model
-        packed = pack_model(params, cfg, block=args.sparse_block) or None
+        packed = pack_model(params, cfg, block=_block(args)) or None
     return params, cfg, packed, "dense"
 
 
@@ -136,11 +152,11 @@ def _run_gateway(args, params, cfg, packed) -> None:
                              "bundle's report.json; pass --artifact")
         place = plan_placement(args.artifact, args.memory_budget,
                                max_seq=max_seq, block_size=args.block_size,
-                               cache_dtype=jnp.float32,
+                               cache_dtype=_dtype(args),
                                scheduler=args.scheduler,
                                prefill_chunk=args.prefill_chunk)
         serve_cfg = dataclasses.replace(place.serve,
-                                        compute_dtype=jnp.float32,
+                                        compute_dtype=_dtype(args),
                                         group_experts=group,
                                         ragged_moe=ragged,
                                         quant=args.quant,
@@ -156,8 +172,8 @@ def _run_gateway(args, params, cfg, packed) -> None:
                                 block_size=args.block_size,
                                 n_blocks=args.n_blocks,
                                 prefill_chunk=args.prefill_chunk,
-                                compute_dtype=jnp.float32,
-                                cache_dtype=jnp.float32,
+                                compute_dtype=_dtype(args),
+                                cache_dtype=_dtype(args),
                                 group_experts=group,
                                 ragged_moe=ragged,
                                 quant=args.quant,
@@ -185,6 +201,7 @@ def _run_gateway(args, params, cfg, packed) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     # surface INFO logs (e.g. pack_model's skipped-projection summary)
     logging.basicConfig(level=logging.INFO, format="%(message)s")
     ap = argparse.ArgumentParser()
@@ -208,7 +225,9 @@ def main() -> None:
                     choices=["unstructured", "structured", "composite"])
     ap.add_argument("--sparse", action="store_true",
                     help="serve pruned MLPs through the block-sparse kernel")
-    ap.add_argument("--sparse-block", type=int, default=16)
+    ap.add_argument("--sparse-block", type=int, default=None,
+                    help="plan tile (default: the recipe's 128; 16 "
+                         "with --smoke)")
     ap.add_argument("--no-group-experts", action="store_true",
                     help="fall back to one block-sparse launch per MoE "
                          "expert instead of the grouped one-launch kernel")
@@ -274,8 +293,8 @@ def main() -> None:
             print("note: --block-size is a continuous-engine flag; "
                   "the static engine always uses a contiguous cache")
         serve_cfg = ServeConfig(max_seq=max_seq,
-                                compute_dtype=jnp.float32,
-                                cache_dtype=jnp.float32,
+                                compute_dtype=_dtype(args),
+                                cache_dtype=_dtype(args),
                                 group_experts=group,
                                 ragged_moe=ragged,
                                 quant=args.quant)
@@ -311,8 +330,8 @@ def main() -> None:
                             block_size=args.block_size,
                             n_blocks=args.n_blocks,
                             prefill_chunk=args.prefill_chunk,
-                            compute_dtype=jnp.float32,
-                            cache_dtype=jnp.float32, group_experts=group,
+                            compute_dtype=_dtype(args),
+                            cache_dtype=_dtype(args), group_experts=group,
                             ragged_moe=ragged, quant=args.quant,
                             paged_kernel=args.paged_kernel,
                             scheduler=args.scheduler)

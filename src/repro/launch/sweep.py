@@ -34,6 +34,7 @@ from repro.configs.registry import get_config, get_smoke_config, list_archs
 from repro.core.rank_controller import RankArtifact
 from repro.core.recipe import CalibrationSpec, PruneRecipe
 from repro.core.sweep import GridSpec, pareto_markdown, run_sweep
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 
 
@@ -52,6 +53,7 @@ def grid_from_args(args: argparse.Namespace) -> GridSpec:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--recipe", default=None, metavar="JSON",
                     help="base PruneRecipe JSON (axes not in the grid "

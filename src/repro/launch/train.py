@@ -21,12 +21,14 @@ import jax.numpy as jnp
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import get_config, get_smoke_config, list_archs
 from repro.data.pipeline import SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="llama3-8b")
     ap.add_argument("--smoke", action="store_true",

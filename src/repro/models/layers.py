@@ -186,16 +186,14 @@ def apply_attention(params: dict, spec: AttentionSpec, x: jax.Array,
                     cache_index: Optional[jax.Array] = None,
                     block_tables: Optional[jax.Array] = None,
                     n_valid: Optional[jax.Array] = None,
-                    paged_kernel: bool = False,
-                    interpret: bool = True):
+                    paged_kernel: bool = False):
     """Returns (out, new_cache). cache: {'k','v': (B, S_max, n_kv, D)},
     or a paged arena {'k','v': (n_blocks, block_size, n_kv, D)} when
     ``block_tables`` (B, max_blocks) maps each sequence's logical blocks
     onto arena blocks; ``n_valid`` (B,) masks right-padded positions of
     a padded (chunked) prefill. ``paged_kernel`` selects the fused Pallas
-    decode kernel (``interpret`` in its CPU interpret mode) on the paged
-    S==1 path; prefill and the default decode path use the gather
-    reference."""
+    decode kernel on the paged S==1 path; prefill and the default decode
+    path use the gather reference."""
     dtype = x.dtype
     tap("attn_qkv", x)
     q = jnp.einsum("bsd,dhe->bshe", x, params["q"].astype(dtype))
@@ -255,7 +253,7 @@ def apply_attention(params: dict, spec: AttentionSpec, x: jax.Array,
             nv1 = (n_valid if n_valid is not None
                    else jnp.full((x.shape[0],), 1, jnp.int32))
             out = paged_attention_decode(q, ck, cv, block_tables,
-                                         ci + nv1, interpret=interpret)
+                                         ci + nv1)
             tap("attn_o", out, channel_axes=(-2, -1))
             y = jnp.einsum("bshe,hed->bsd", out, params["o"].astype(dtype))
             return hint(y, "batch", "seq", "embed"), new_cache

@@ -166,7 +166,7 @@ def apply_block(block_params: dict, cfg: ModelConfig, spec: LayerSpec,
                 layer: int = 0, mlp_apply=None,
                 block_tables: Optional[jax.Array] = None,
                 n_valid: Optional[jax.Array] = None,
-                paged_kernel: bool = False, interpret: bool = True):
+                paged_kernel: bool = False):
     h = L.apply_norm(block_params["norm1"], cfg.norm, x)
     new_cache = {}
     if isinstance(spec.mixer, AttentionSpec):
@@ -174,7 +174,7 @@ def apply_block(block_params: dict, cfg: ModelConfig, spec: LayerSpec,
             block_params["attn"], spec.mixer, h, positions,
             cache["attn"] if cache is not None else None, cache_index,
             block_tables=block_tables, n_valid=n_valid,
-            paged_kernel=paged_kernel, interpret=interpret)
+            paged_kernel=paged_kernel)
         if nc is not None:
             new_cache["attn"] = nc
     else:
@@ -206,7 +206,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
             compute_dtype=jnp.bfloat16, mlp_apply=None,
             block_tables: Optional[jax.Array] = None,
             n_valid: Optional[jax.Array] = None,
-            paged_kernel: bool = False, interpret: bool = True):
+            paged_kernel: bool = False):
     """Returns (logits, new_cache, aux_loss).
 
     tokens: (B, S) int32. frontend_embeds: (B, F, d) stub embeddings that
@@ -218,8 +218,8 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     gathered through its block-table row; ``n_valid`` (B,) masks
     right-padded positions of a padded (chunked) prefill into the
     scratch block. Unrolled configs only. ``paged_kernel`` routes paged
-    S==1 steps through the fused Pallas decode kernel (``interpret``
-    selects its CPU interpret mode) instead of the gather path.
+    S==1 steps through the fused Pallas decode kernel instead of the
+    gather path.
     mlp_apply: optional ``(block_params, ffn_spec, x, layer) -> y``
     override for FFN layers (``ffn_spec`` is an ``MLPSpec`` or
     ``MoESpec``) — the serving block-sparse fast path; MoE layers run
@@ -293,8 +293,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
                                    mlp_apply=mlp_apply,
                                    block_tables=block_tables,
                                    n_valid=n_valid,
-                                   paged_kernel=paged_kernel,
-                                   interpret=interpret)
+                                   paged_kernel=paged_kernel)
             if cfg.remat:
                 body = jax.checkpoint(
                     body, policy=jax.checkpoint_policies.nothing_saveable)

@@ -174,7 +174,6 @@ class ContinuousEngine:
                  max_slots: Optional[int] = None,
                  max_seq: Optional[int] = None, compute_dtype=None,
                  cache_dtype=None, packed: Optional[dict] = None,
-                 interpret: Optional[bool] = None,
                  prefill_multiple: Optional[int] = None,
                  group_experts: Optional[bool] = None):
         if isinstance(serve, int):  # legacy positional (max_slots, max_seq)
@@ -184,7 +183,7 @@ class ContinuousEngine:
         if serve is None:
             serve = _legacy_serve_config(
                 "ContinuousEngine", max_slots, max_seq, compute_dtype,
-                cache_dtype, interpret, prefill_multiple, group_experts)
+                cache_dtype, prefill_multiple, group_experts)
         if cfg.scan_layers:
             raise ValueError("continuous batching needs an unrolled config "
                              "(cfg.replace(scan_layers=False))")
@@ -209,8 +208,7 @@ class ContinuousEngine:
         # per-stage observability: request latencies + tick gauges land
         # here (host-side ring buffers; the gateway's /metrics source)
         self.metrics = MetricsRegistry()
-        mlp_apply = (make_sparse_mlp_apply(packed, serve.interpret,
-                                           serve.group_experts,
+        mlp_apply = (make_sparse_mlp_apply(packed, serve.group_experts,
                                            serve.ragged_moe, serve.quant)
                      if packed else None)
         if serve.paged:
@@ -218,8 +216,7 @@ class ContinuousEngine:
                 cfg, serve.compute_dtype, mlp_apply))
             decode = make_paged_decode_step(
                 cfg, serve.compute_dtype, mlp_apply,
-                paged_kernel=serve.paged_kernel,
-                interpret=serve.interpret)
+                paged_kernel=serve.paged_kernel)
             self._copy_block = jax.jit(T.copy_pool_block)
         else:
             self._prefill = jax.jit(make_prefill_step(

@@ -56,7 +56,6 @@ class ServeConfig:
     paged_kernel: bool = False      # paged decode: fused Pallas
     #                                 paged-attention kernel instead of
     #                                 the gather path (needs block_size)
-    interpret: bool = True          # Pallas interpret mode (CPU)
     scheduler: str = "fifo"         # admission policy name from
     #                                 repro.serve.policies.SCHEDULERS:
     #                                 fifo | priority | slo

@@ -26,7 +26,7 @@ from repro.models.specs import ModelConfig
 from repro.serve.config import ServeConfig
 
 
-def make_sparse_mlp_apply(packed: dict, interpret: bool = True,
+def make_sparse_mlp_apply(packed: dict,
                           group_experts: Optional[bool] = None,
                           ragged_moe: Optional[bool] = None,
                           quant: Optional[str] = None):
@@ -54,7 +54,6 @@ def make_sparse_mlp_apply(packed: dict, interpret: bool = True,
 
     def mlp_apply(block_params, spec, x, layer):
         return sparse_apply_ffn(block_params, spec, x, packed, layer,
-                                interpret=interpret,
                                 group_experts=group_experts,
                                 ragged_moe=ragged_moe, quant=quant)
     return mlp_apply
@@ -125,7 +124,7 @@ def request_key(seed: Optional[int], uid: int, run_seed: int) -> jax.Array:
 
 
 def _legacy_serve_config(engine: str, max_slots, max_seq, compute_dtype,
-                         cache_dtype, interpret, prefill_multiple,
+                         cache_dtype, prefill_multiple,
                          group_experts) -> ServeConfig:
     """Assemble a ServeConfig from pre-redesign kwargs (deprecated)."""
     warnings.warn(
@@ -133,8 +132,7 @@ def _legacy_serve_config(engine: str, max_slots, max_seq, compute_dtype,
         "deprecated; pass a repro.serve.config.ServeConfig",
         DeprecationWarning, stacklevel=3)
     kw = dict(max_seq=max_seq, compute_dtype=compute_dtype,
-              cache_dtype=cache_dtype, interpret=interpret,
-              group_experts=group_experts)
+              cache_dtype=cache_dtype, group_experts=group_experts)
     if max_slots is not None:
         kw["max_slots"] = max_slots
     if prefill_multiple is not None:
@@ -156,21 +154,19 @@ class Engine:
                  max_seq: Optional[int] = None,
                  compute_dtype=None, cache_dtype=None,
                  packed: Optional[dict] = None,
-                 interpret: Optional[bool] = None,
                  group_experts: Optional[bool] = None):
         if isinstance(serve, int):      # legacy positional max_seq
             serve, max_seq = None, serve
         if serve is None:
             serve = _legacy_serve_config(
                 "Engine", None, max_seq, compute_dtype, cache_dtype,
-                interpret, None, group_experts)
+                None, group_experts)
         self.serve = serve
         self.params = params
         self.cfg = cfg
         self.max_seq = serve.max_seq
         self.cache_dtype = serve.cache_dtype
-        mlp_apply = (make_sparse_mlp_apply(packed, serve.interpret,
-                                           serve.group_experts,
+        mlp_apply = (make_sparse_mlp_apply(packed, serve.group_experts,
                                            serve.ragged_moe, serve.quant)
                      if packed else None)
         self.prefill_step = jax.jit(

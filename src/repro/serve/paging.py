@@ -198,8 +198,7 @@ def make_paged_prefill_step(cfg: ModelConfig, compute_dtype=jnp.bfloat16,
 
 
 def make_paged_decode_step(cfg: ModelConfig, compute_dtype=jnp.bfloat16,
-                           mlp_apply=None, paged_kernel: bool = False,
-                           interpret: bool = True):
+                           mlp_apply=None, paged_kernel: bool = False):
     """One token for every slot against the paged pool: the per-slot
     ``lengths`` vector and ``block_tables`` play the role the vector
     ``cache_index`` plays for the contiguous pool. ``paged_kernel``
@@ -210,7 +209,6 @@ def make_paged_decode_step(cfg: ModelConfig, compute_dtype=jnp.bfloat16,
         logits, pool, _ = T.forward(
             params, cfg, tokens, cache=pool, cache_index=lengths,
             block_tables=block_tables, compute_dtype=compute_dtype,
-            mlp_apply=mlp_apply, paged_kernel=paged_kernel,
-            interpret=interpret)
+            mlp_apply=mlp_apply, paged_kernel=paged_kernel)
         return logits[:, -1, :], pool
     return paged_decode_step

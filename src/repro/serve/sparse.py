@@ -10,8 +10,9 @@ the MoE dispatch). MoE expert matmuls default to the *grouped*
 block-sparse kernel — all E experts in one launch, driven directly by
 the stacked plan — with the per-expert launch loop kept as the
 ``group_experts=False`` fallback (and the reference in equivalence
-tests). On TPU the skipped tiles are real MXU/HBM savings; on CPU the
-kernels run in interpret mode (tests assert exact agreement with dense).
+tests). On TPU the skipped tiles are real MXU/HBM savings; on the CPU
+backend the kernels run in interpret mode (``repro.kernels.
+interpret_mode``; tests assert exact agreement with dense).
 """
 from __future__ import annotations
 
@@ -343,7 +344,7 @@ def _use_quant(plan, quant: Optional[str]) -> bool:
     return False
 
 
-def sparse_linear(x, w, packed: PackedProjection, interpret: bool = True,
+def sparse_linear(x, w, packed: PackedProjection,
                   quant: Optional[str] = None):
     """y = x @ w through the block-sparse kernel. x: (..., K); w: (K, N).
     Quantized plans stream their int8 kept tiles instead of ``w``
@@ -361,19 +362,18 @@ def sparse_linear(x, w, packed: PackedProjection, interpret: bool = True,
         y = quant_blocksparse_matmul(x2, packed.tiles, packed.counts,
                                      packed.indices, packed.slots,
                                      packed.scales, block_m=bm, block_k=bm,
-                                     block_n=bm, interpret=interpret)
+                                     block_n=bm)
     else:
         y = blocksparse_matmul(x2, w.reshape(K, -1), packed.counts,
                                packed.indices, block_m=bm, block_k=bm,
-                               block_n=bm, interpret=interpret)
+                               block_n=bm)
     if pad_m:
         y = y[:M]
     return y.reshape(*lead, -1)
 
 
 def sparse_apply_mlp(block_params: dict, spec, x, packed_layer: dict,
-                     layer: int, interpret: bool = True,
-                     quant: Optional[str] = None):
+                     layer: int, quant: Optional[str] = None):
     """Feed-forward through the kernel (gate/up/down as available)."""
     from repro.models.layers import activation
     mlp = block_params["mlp"]
@@ -383,8 +383,7 @@ def sparse_apply_mlp(block_params: dict, spec, x, packed_layer: dict,
         w = mlp[name].astype(dtype)
         key = (layer, name)
         if key in packed_layer:
-            return sparse_linear(inp, w, packed_layer[key], interpret,
-                                 quant=quant)
+            return sparse_linear(inp, w, packed_layer[key], quant=quant)
         return inp @ w
 
     up = lin("up", x)
@@ -396,8 +395,7 @@ def sparse_apply_mlp(block_params: dict, spec, x, packed_layer: dict,
 
 
 def grouped_sparse_linear(xs, ws, packed: PackedExpertProjection,
-                          interpret: bool = True, row_live=None,
-                          quant: Optional[str] = None):
+                          row_live=None, quant: Optional[str] = None):
     """y[e] = x[e] @ w[e] for all experts in ONE grouped kernel launch.
     xs: (E, M, K); ws: (E, K, ...) — trailing dims folded to N. Decode-
     sized slot batches keep the whole M panel resident per expert
@@ -423,13 +421,12 @@ def grouped_sparse_linear(xs, ws, packed: PackedExpertProjection,
         y = quant_grouped_blocksparse_matmul(
             xs, packed.tiles, packed.counts, packed.indices, packed.slots,
             packed.scales, block_m=block_m, block_k=bm, block_n=bm,
-            interpret=interpret, row_live=row_live)
+            row_live=row_live)
     else:
         y = grouped_blocksparse_matmul(xs, ws.reshape(E, K, -1),
                                        packed.counts, packed.indices,
                                        block_m=block_m, block_k=bm,
-                                       block_n=bm, interpret=interpret,
-                                       row_live=row_live)
+                                       block_n=bm, row_live=row_live)
     if pad_m:
         y = y[:, :M]
     return y
@@ -437,7 +434,6 @@ def grouped_sparse_linear(xs, ws, packed: PackedExpertProjection,
 
 def ragged_sparse_linear(xp, ws, tile_expert,
                          packed: PackedExpertProjection,
-                         interpret: bool = True,
                          quant: Optional[str] = None):
     """The ragged expert batch through the stacked tile plan in one
     launch. xp: (M, K) routed tokens packed into tile-aligned per-expert
@@ -456,13 +452,12 @@ def ragged_sparse_linear(xp, ws, tile_expert,
         return quant_ragged_blocksparse_matmul(
             xp, packed.tiles, packed.counts, packed.indices, packed.slots,
             packed.scales, tile_expert, block_m=RAGGED_BLOCK_ROWS,
-            block_k=bm, block_n=bm, interpret=interpret)
+            block_k=bm, block_n=bm)
     return ragged_blocksparse_matmul(xp, ws.reshape(E, K, -1),
                                      packed.counts, packed.indices,
                                      tile_expert,
                                      block_m=RAGGED_BLOCK_ROWS,
-                                     block_k=bm, block_n=bm,
-                                     interpret=interpret)
+                                     block_k=bm, block_n=bm)
 
 
 # Largest token count (B*S at the layer input) served through the
@@ -474,8 +469,7 @@ RAGGED_TOKENS_MAX = 64
 
 
 def sparse_apply_moe(block_params: dict, spec, x, packed_layer: dict,
-                     layer: int, interpret: bool = True,
-                     group_experts: Optional[bool] = None,
+                     layer: int, group_experts: Optional[bool] = None,
                      ragged_moe: Optional[bool] = None,
                      quant: Optional[str] = None):
     """MoE feed-forward with the expert matmuls run through the
@@ -516,7 +510,7 @@ def sparse_apply_moe(block_params: dict, spec, x, packed_layer: dict,
             plan = packed_layer.get((layer, name))
             if isinstance(plan, PackedExpertProjection):
                 return ragged_sparse_linear(xp, ws, tile_expert, plan,
-                                            interpret, quant=quant)
+                                            quant=quant)
             # no plan for this projection: per-row expert gather oracle
             from repro.kernels.grouped_block_sparse.ops import \
                 RAGGED_BLOCK_ROWS
@@ -532,7 +526,7 @@ def sparse_apply_moe(block_params: dict, spec, x, packed_layer: dict,
         def expert_group_linear(name, xs, ws, row_live):
             plan = packed_layer.get((layer, name))
             if isinstance(plan, PackedExpertProjection):
-                return grouped_sparse_linear(xs, ws, plan, interpret,
+                return grouped_sparse_linear(xs, ws, plan,
                                              row_live=row_live,
                                              quant=quant)
             return jnp.einsum("emk,ekn->emn", xs, ws)
@@ -544,8 +538,7 @@ def sparse_apply_moe(block_params: dict, spec, x, packed_layer: dict,
     def expert_linear(name, e, xe, we):
         plan = packed_layer.get((layer, name))
         if isinstance(plan, PackedExpertProjection):
-            return sparse_linear(xe, we, plan.expert(e), interpret,
-                                 quant=quant)
+            return sparse_linear(xe, we, plan.expert(e), quant=quant)
         return xe @ we
 
     y, _ = apply_moe(block_params["moe"], spec, x,
@@ -554,8 +547,7 @@ def sparse_apply_moe(block_params: dict, spec, x, packed_layer: dict,
 
 
 def sparse_apply_ffn(block_params: dict, spec, x, packed: dict,
-                     layer: int, interpret: bool = True,
-                     group_experts: Optional[bool] = None,
+                     layer: int, group_experts: Optional[bool] = None,
                      ragged_moe: Optional[bool] = None,
                      quant: Optional[str] = None):
     """Feed-forward dispatch for the serving ``mlp_apply`` hook: dense-MLP
@@ -569,9 +561,9 @@ def sparse_apply_ffn(block_params: dict, spec, x, packed: dict,
     from repro.models.specs import MoESpec
     if isinstance(spec, MoESpec):
         return sparse_apply_moe(block_params, spec, x, packed, layer,
-                                interpret, group_experts=group_experts,
+                                group_experts=group_experts,
                                 ragged_moe=ragged_moe, quant=quant)
-    return sparse_apply_mlp(block_params, spec, x, packed, layer, interpret,
+    return sparse_apply_mlp(block_params, spec, x, packed, layer,
                             quant=quant)
 
 

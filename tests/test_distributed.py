@@ -58,8 +58,7 @@ def test_compressed_psum_accuracy_and_error_feedback():
         def f(gl):
             red, e = compressed_psum({"g": gl}, "pod")
             return red["g"], e["g"]
-        from repro.common.compat import shard_map
-        fm = shard_map(f, mesh=mesh, in_specs=P("pod"),
+        fm = jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
                            out_specs=(P(), P("pod")))
         red, e = fm(g)
         print(json.dumps({

@@ -185,11 +185,11 @@ def test_ragged_grouped_loop_dense_identical(E, top_k, cf, B, seed):
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (B, 1, 32),
                          jnp.float32)
     y_dense, _ = moe.apply_moe(params, spec, x)
-    y_rag = sparse_apply_moe(bp, spec, x, packed, 0, interpret=True,
+    y_rag = sparse_apply_moe(bp, spec, x, packed, 0,
                              ragged_moe=True)
-    y_grp = sparse_apply_moe(bp, spec, x, packed, 0, interpret=True,
+    y_grp = sparse_apply_moe(bp, spec, x, packed, 0,
                              group_experts=True, ragged_moe=False)
-    y_loop = sparse_apply_moe(bp, spec, x, packed, 0, interpret=True,
+    y_loop = sparse_apply_moe(bp, spec, x, packed, 0,
                               group_experts=False, ragged_moe=False)
     np.testing.assert_array_equal(np.asarray(y_rag), np.asarray(y_grp))
     np.testing.assert_array_equal(np.asarray(y_rag), np.asarray(y_loop))
@@ -206,10 +206,10 @@ def test_ragged_falls_back_to_grouped_on_prefill_sizes():
     S = RAGGED_TOKENS_MAX + 1
     x = jax.random.normal(jax.random.PRNGKey(9), (1, S, 32), jnp.float32)
     counters.reset()
-    y_rag = sparse_apply_moe(bp, spec, x, packed, 0, interpret=True,
+    y_rag = sparse_apply_moe(bp, spec, x, packed, 0,
                              ragged_moe=True)
     assert "grouped_block_sparse_ragged" not in counters.snapshot()
-    y_grp = sparse_apply_moe(bp, spec, x, packed, 0, interpret=True,
+    y_grp = sparse_apply_moe(bp, spec, x, packed, 0,
                              group_experts=True, ragged_moe=False)
     np.testing.assert_array_equal(np.asarray(y_rag), np.asarray(y_grp))
 

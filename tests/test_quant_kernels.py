@@ -134,8 +134,8 @@ def test_block_sparse_quant_bitwise(dtype):
     p = pack_projection(w, BLOCK, quant="int8")
     wfq = jnp.asarray(dequantized_weight(p, 64), dtype)
     x = jax.random.normal(jax.random.PRNGKey(1), (32, 64), dtype)
-    y_q = sparse_linear(x, wfq, p, interpret=True, quant="int8")
-    y_ref = sparse_linear(x, wfq, p, interpret=True, quant="none")
+    y_q = sparse_linear(x, wfq, p, quant="int8")
+    y_ref = sparse_linear(x, wfq, p, quant="none")
     assert y_q.dtype == y_ref.dtype
     np.testing.assert_array_equal(np.asarray(y_q), np.asarray(y_ref))
 

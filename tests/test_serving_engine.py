@@ -107,8 +107,11 @@ def test_vector_cache_index_matches_scalar(served):
         lo_ref, _, _ = T.forward(params, cfg, new[i:i + 1], cache=c,
                                  cache_index=jnp.int32(n),
                                  compute_dtype=jnp.float32)
-        np.testing.assert_array_equal(np.asarray(lo_vec[i]),
-                                      np.asarray(lo_ref[0]))
+        # B=2 and B=1 forwards are different XLA programs: float32
+        # reductions may reassociate across batch sizes, which moves the
+        # logits by about one ulp (max 1.8e-6 observed), never more
+        np.testing.assert_allclose(np.asarray(lo_vec[i]),
+                                   np.asarray(lo_ref[0]), rtol=0, atol=1e-5)
 
 
 def test_cache_pool_requires_unrolled(served):
@@ -229,6 +232,6 @@ def test_sparse_engine_matches_dense_interpret(pruned_served):
               cache_dtype=jnp.float32)
     dense, _ = ContinuousEngine(params, cfg, **kw).run(reqs)
     sparse, _ = ContinuousEngine(params, cfg, packed=packed,
-                                 interpret=True, **kw).run(reqs)
+                                 **kw).run(reqs)
     for d, s in zip(dense, sparse):
         assert d.tokens == s.tokens, f"uid {d.request.uid} diverged"

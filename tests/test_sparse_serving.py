@@ -45,7 +45,7 @@ def test_sparse_linear_matches_dense(pruned):
     w = params["blocks"][0]["mlp"]["up"]
     packed = pack_projection(w, block=16)
     x = jax.random.normal(jax.random.PRNGKey(3), (5, w.shape[0]))
-    y_sparse = sparse_linear(x, w, packed, interpret=True)
+    y_sparse = sparse_linear(x, w, packed)
     np.testing.assert_allclose(np.asarray(y_sparse), np.asarray(x @ w),
                                rtol=2e-5, atol=2e-5)
 
@@ -58,7 +58,7 @@ def test_sparse_mlp_matches_dense(pruned):
     from repro.models.layers import apply_mlp
     y_dense = apply_mlp(params["blocks"][0]["mlp"], spec, x)
     y_sparse = sparse_apply_mlp(params["blocks"][0], spec, x, packed,
-                                layer=0, interpret=True)
+                                layer=0)
     np.testing.assert_allclose(np.asarray(y_sparse), np.asarray(y_dense),
                                rtol=2e-4, atol=2e-4)
 
