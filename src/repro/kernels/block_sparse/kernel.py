@@ -105,6 +105,7 @@ def quant_block_sparse_matmul(x: jax.Array, tiles: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
+        name="blocksparse_int8",
     )(counts, indices, slots, scales, x, tiles)
 
 
@@ -144,4 +145,8 @@ def block_sparse_matmul(x: jax.Array, w: jax.Array, counts: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
+        # not "block_sparse": bench/devtrace.py finds that text anywhere
+        # in an op, operands included, so every consumer of the result
+        # (the LM head among them) would count as this kernel's time
+        name="blocksparse",
     )(counts, indices, x, w)

@@ -133,5 +133,6 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((B, n_kv, group, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables, lengths, q.reshape(B, n_kv, group, D), k_view, v_view)
     return out.reshape(B, n_q, D)

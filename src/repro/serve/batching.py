@@ -56,7 +56,7 @@ from repro.serve.engine import (_legacy_serve_config, make_prefill_step,
 from repro.serve.metrics import (MetricsRegistry,  # noqa: F401 (re-export)
                                  latency_percentiles)
 from repro.serve.paging import (BlockAllocator, PrefixCache,
-                                make_paged_decode_step,
+                                block_ticks_used, make_paged_decode_step,
                                 make_paged_prefill_step)
 from repro.serve.scheduler import Scheduler
 
@@ -88,8 +88,8 @@ class _BatchFeed:
     loop exits when the scheduler drains, and idles by sleeping until
     the next future arrival (PR 6 semantics, bitwise-preserving)."""
 
-    def pump(self, sched, now: float) -> None:
-        pass
+    def pump(self, sched, now: float) -> int:
+        return 0
 
     def drained(self) -> bool:
         return True
@@ -127,7 +127,9 @@ class _QueueFeed(_BatchFeed):
         self.poll_s = poll_s
         self._staged: list = []
 
-    def pump(self, sched, now: float) -> None:
+    def pump(self, sched, now: float) -> int:
+        """Move the inbox into the scheduler; returns how many moved."""
+        n = 0
         while True:
             if self._staged:
                 req = self._staged.pop(0)
@@ -140,6 +142,8 @@ class _QueueFeed(_BatchFeed):
             # engine clock — queue-wait metrics start here
             req.arrival = now
             sched.submit(req)
+            n += 1
+        return n
 
     def drained(self) -> bool:
         return (self.stop is not None and self.stop.is_set()
@@ -277,9 +281,10 @@ class ContinuousEngine:
 
     def _sample_first(self, logits_row, slot, state):
         """Sample the request's first token from its prefill logits."""
-        return int(self._first_sample(
-            logits_row, jnp.asarray(state["bases"][slot.index]),
-            jnp.asarray(state["temps"][slot.index], jnp.float32)))
+        with M.span("engine.first_token", uid=slot.request.uid):
+            return int(self._first_sample(
+                logits_row, jnp.asarray(state["bases"][slot.index]),
+                jnp.asarray(state["temps"][slot.index], jnp.float32)))
 
     # ----------------------------------------------------------- prefill
 
@@ -306,17 +311,19 @@ class ContinuousEngine:
         chunk = serve.prefill_chunk or (s0 - start)
         end = min(start + chunk, s0)
         n = end - start
-        bucket = self._bucket(n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = prompt[start:end]
-        logits, pool = self._prefill(
-            self.params, pool, jnp.asarray(padded),
-            jnp.asarray(tables[slot.index:slot.index + 1]),
-            jnp.asarray([start], jnp.int32), jnp.asarray([n], jnp.int32))
-        slot.prefilled = end
-        if end < s0:
-            return pool, None
-        return pool, self._sample_first(logits[0, n - 1, :], slot, state)
+        with M.span("engine.prefill_chunk", uid=slot.request.uid,
+                    start=start, tokens=n):
+            bucket = self._bucket(n)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :n] = prompt[start:end]
+            logits, pool = self._prefill(
+                self.params, pool, jnp.asarray(padded),
+                jnp.asarray(tables[slot.index:slot.index + 1]),
+                jnp.asarray([start], jnp.int32), jnp.asarray([n], jnp.int32))
+            slot.prefilled = end
+            if end < s0:
+                return pool, None
+            return pool, self._sample_first(logits[0, n - 1, :], slot, state)
 
     # -------------------------------------------------------------- run
 
@@ -414,50 +421,65 @@ class ContinuousEngine:
         if not active:
             return None
         t_burst = time.perf_counter()
-        tokens_in = np.zeros((self.max_slots, 1), np.int32)
-        lengths = np.zeros((self.max_slots,), np.int32)
-        counts = np.zeros((self.max_slots,), np.int32)
-        for s in active:
-            tokens_in[s.index, 0] = s.last_token
-            lengths[s.index] = s.length
-            counts[s.index] = len(s.generated)
         remaining = min(
             min(s.request.max_new_tokens - len(s.generated),
                 self.max_seq - s.length) for s in active)
         burst = max(1, min(max_burst, remaining))
         if max_ticks is not None:
             burst = min(burst, max_ticks - tick_state["ticks"])
-        extra = ((jnp.asarray(tables),) if tables is not None else ())
-        toks_dev = jnp.asarray(tokens_in)
-        lens_dev = jnp.asarray(lengths)
-        counts_dev = jnp.asarray(counts)
-        bases_dev = jnp.asarray(state["bases"])
-        temps_dev = jnp.asarray(state["temps"])
-        steps = []
-        for _ in range(burst):
-            sampled, pool = self._decode_sample(
-                self.params, pool, toks_dev, lens_dev, bases_dev,
-                counts_dev, temps_dev, *extra)
-            steps.append(sampled)
-            toks_dev = sampled[:, None]
-            lens_dev = lens_dev + 1
-            counts_dev = counts_dev + 1
-        host = np.asarray(jnp.stack(steps))    # one sync per burst
-        for k in range(burst):
-            sched.decoded({s.index: host[k, s.index] for s in active},
-                          tick_state["clock"]())
-            tick_state["util"].append(len(active) / self.max_slots)
-            tick_state["ticks"] += 1
-        burst_s = time.perf_counter() - t_burst
-        m = self.metrics
-        m.observe("tick.active_slots", len(active))
-        m.observe("tick.prefill_backlog",
-                  len(sched.prefilling) + len(sched.queue))
-        if burst_s > 0:
-            m.gauge("tick.tokens_per_s", burst * len(active) / burst_s)
-        m.count("decode.ticks", burst)
-        m.count("decode.tokens", burst * len(active))
+        tick_state["bursts"] += 1
+        with M.span("engine.burst", burst=tick_state["bursts"],
+                    slots=len(active), ticks=burst,
+                    ctx=sum(s.length for s in active)):
+            with M.span("engine.burst.prepare"):
+                tokens_in = np.zeros((self.max_slots, 1), np.int32)
+                lengths = np.zeros((self.max_slots,), np.int32)
+                counts = np.zeros((self.max_slots,), np.int32)
+                for s in active:
+                    tokens_in[s.index, 0] = s.last_token
+                    lengths[s.index] = s.length
+                    counts[s.index] = len(s.generated)
+                extra = ((jnp.asarray(tables),) if tables is not None
+                         else ())
+                toks_dev = jnp.asarray(tokens_in)
+                lens_dev = jnp.asarray(lengths)
+                counts_dev = jnp.asarray(counts)
+                bases_dev = jnp.asarray(state["bases"])
+                temps_dev = jnp.asarray(state["temps"])
+            with M.span("engine.burst.dispatch"):
+                steps = []
+                for _ in range(burst):
+                    sampled, pool = self._decode_sample(
+                        self.params, pool, toks_dev, lens_dev, bases_dev,
+                        counts_dev, temps_dev, *extra)
+                    steps.append(sampled)
+                    toks_dev = sampled[:, None]
+                    lens_dev = lens_dev + 1
+                    counts_dev = counts_dev + 1
+            with M.span("engine.burst.sync"):
+                host = np.asarray(jnp.stack(steps))    # one sync per burst
+            with M.span("engine.burst.emit", tokens=burst * len(active)):
+                for k in range(burst):
+                    sched.decoded({s.index: host[k, s.index]
+                                   for s in active}, tick_state["clock"]())
+                    tick_state["ticks"] += 1
+                burst_s = time.perf_counter() - t_burst
+                m = self.metrics
+                m.observe("tick.active_slots", len(active))
+                m.observe("tick.prefill_backlog",
+                          len(sched.prefilling) + len(sched.queue))
+                if burst_s > 0:
+                    m.gauge("tick.tokens_per_s",
+                            burst * len(active) / burst_s)
+                m.count("decode.ticks", burst)
+                m.count("decode.tokens", burst * len(active))
         return pool
+
+    def _tick_state(self, clock) -> dict:
+        """Per-run loop state; ``tokens0`` is where the registry's
+        ``decode.tokens`` stood when the run began."""
+        return {"ticks": 0, "bursts": 0, "peak": 0, "clock": clock,
+                "tokens0": self.metrics.counters.get("decode.tokens", 0.0)}
 
     def _stats(self, sched, tick_state, wall, prefills, chunks):
         finished = sorted(sched.finished, key=lambda f: f.request.uid)
@@ -469,12 +491,15 @@ class ContinuousEngine:
             shareable = sum((len(f.request.prompt) - 1) // bs
                             for f in finished
                             if f.request.prefix_id is not None)
-        util = tick_state["util"]
+        ticks = tick_state["ticks"]
+        tokens = (self.metrics.counters.get("decode.tokens", 0.0)
+                  - tick_state["tokens0"])
         return finished, ServeStats(
-            ticks=tick_state["ticks"], wall_s=wall,
+            ticks=ticks, wall_s=wall,
             generated_tokens=n_tok,
             tokens_per_s=n_tok / wall if wall > 0 else 0.0,
-            slot_utilization=float(np.mean(util)) if util else 0.0,
+            slot_utilization=(tokens / (ticks * self.max_slots)
+                              if ticks else 0.0),
             prefills=prefills, rejected=len(sched.rejected),
             prefill_chunks=chunks,
             peak_concurrency=tick_state["peak"],
@@ -490,7 +515,7 @@ class ContinuousEngine:
                                  self.cache_dtype)
         t0 = time.perf_counter()
         clock = lambda: time.perf_counter() - t0  # noqa: E731
-        tick_state = {"ticks": 0, "util": [], "peak": 0, "clock": clock}
+        tick_state = self._tick_state(clock)
         prefills = 0
 
         while True:
@@ -548,7 +573,7 @@ class ContinuousEngine:
         slot_reserve: dict[int, int] = {}
         t0 = time.perf_counter()
         clock = lambda: time.perf_counter() - t0  # noqa: E731
-        tick_state = {"ticks": 0, "util": [], "peak": 0, "clock": clock}
+        tick_state = self._tick_state(clock)
         prefills = chunks = 0
 
         # blocks are *reserved* inside the admission gate — several
@@ -563,6 +588,7 @@ class ContinuousEngine:
             shared = prefix.match(req.prefix_id, req.prompt)
             need = self._blocks_for(req, prefix)
             if need > alloc.n_free:
+                self.metrics.count("kv.admit_blocked")
                 return False
             if shared:
                 alloc.retain(shared)
@@ -581,55 +607,65 @@ class ContinuousEngine:
                 tables[slot.index, :] = alloc.scratch
 
         while True:
-            feed.pump(sched, clock())
+            with M.span("engine.pump") as sp:
+                sp.set_metadata(submitted=feed.pump(sched, clock()))
             if not sched.has_work():
                 if feed.drained():
                     break
-                feed.wait(sched, clock)
+                with M.span("engine.wait"):
+                    feed.wait(sched, clock)
                 continue
             if max_ticks is not None and tick_state["ticks"] >= max_ticks:
                 break
 
             # ---- admissions: map shared prefix blocks + claim the rest
-            admitted = sched.admissions(clock(), can_admit)
-            if (not admitted and not sched.slots and not sched.prefilling
-                    and sched.head(clock()) is not None):
-                # head blocked with the pool idle: cached prefixes are
-                # the only block holders — drop them and retry; a head
-                # that still doesn't fit can never run
-                if len(prefix):
-                    prefix.drop_all()
-                    admitted = sched.admissions(clock(), can_admit)
-                head = sched.head(clock())
-                if (not admitted and head is not None
-                        and not can_admit(head)):
-                    sched.reject(sched.pop_head(), "insufficient_blocks",
-                                 clock())
-                    continue
-            for slot in admitted:
-                req = slot.request
-                shared, owned = pending.pop(req.uid)
-                if shared:
-                    # the last claimed block is the COW reserve: held
-                    # outside the table until a shared-block write needs
-                    # a private copy (or released at finish, unused)
-                    slot_reserve[slot.index] = owned.pop()
-                row = shared + owned
-                tables[slot.index, :] = alloc.scratch
-                tables[slot.index, :len(row)] = row
-                slot_blocks[slot.index] = row
-                slot.shared_blocks = len(shared)
-                slot.prefilled = len(shared) * bs
-                self._request_sampling(slot, state,
-                                       state["default_temp"],
-                                       state["run_seed"])
-            for shared, owned in pending.values():  # reserved, not admitted
-                if shared:
-                    alloc.release(shared)
-                alloc.release(owned)
-            pending.clear()
-            tick_state["peak"] = max(tick_state["peak"],
-                                     sched.concurrency())
+            with M.span("engine.admit") as sp:
+                admitted = sched.admissions(clock(), can_admit)
+                if (not admitted and not sched.slots
+                        and not sched.prefilling
+                        and sched.head(clock()) is not None):
+                    # head blocked with the pool idle: cached prefixes
+                    # are the only block holders — drop them and retry;
+                    # a head that still doesn't fit can never run
+                    if len(prefix):
+                        prefix.drop_all()
+                        admitted = sched.admissions(clock(), can_admit)
+                    head = sched.head(clock())
+                    if (not admitted and head is not None
+                            and not can_admit(head)):
+                        sched.reject(sched.pop_head(), "insufficient_blocks",
+                                     clock())
+                        continue
+                claimed = 0
+                for slot in admitted:
+                    req = slot.request
+                    shared, owned = pending.pop(req.uid)
+                    claimed += len(owned)
+                    if shared:
+                        # the last claimed block is the COW reserve: held
+                        # outside the table until a shared-block write
+                        # needs a private copy (or released at finish,
+                        # unused)
+                        slot_reserve[slot.index] = owned.pop()
+                    row = shared + owned
+                    tables[slot.index, :] = alloc.scratch
+                    tables[slot.index, :len(row)] = row
+                    slot_blocks[slot.index] = row
+                    slot.shared_blocks = len(shared)
+                    slot.prefilled = len(shared) * bs
+                    self._request_sampling(slot, state,
+                                           state["default_temp"],
+                                           state["run_seed"])
+                # reserved, not admitted
+                for shared, owned in pending.values():
+                    if shared:
+                        alloc.release(shared)
+                    alloc.release(owned)
+                pending.clear()
+                tick_state["peak"] = max(tick_state["peak"],
+                                         sched.concurrency())
+                sp.set_metadata(admitted=len(admitted),
+                                blocks_claimed=claimed)
 
             # ---- chunked prefill: one chunk per prefilling slot per
             # tick, interleaved with the decode burst below; the policy
@@ -661,46 +697,71 @@ class ContinuousEngine:
             # comes out of the slot's admission-time reserve, never a
             # fresh alloc — a full arena here must not raise OutOfBlocks
             active = sched.active()
-            for s in active:
-                j = s.length // bs
-                old = int(tables[s.index][j])
-                reserve = slot_reserve.get(s.index)
-                pool = alloc.ensure_writable(
-                    tables[s.index], j, pool, reserve=reserve)
-                new = int(tables[s.index][j])
-                if new != old:
-                    # the ownership list must track the swap: the shared
-                    # block's ref was dropped by ensure_writable; the
-                    # private copy is released at finish instead
-                    row = slot_blocks[s.index]
-                    row[row.index(old)] = new
-                    slot_reserve.pop(s.index, None)
-                elif reserve is not None:
-                    # first guarded decode tick and no copy was needed:
-                    # every shared block sits strictly below the write
-                    # frontier and the frontier block is now exclusively
-                    # ours, so COW can never fire for this slot again —
-                    # return the reserve instead of taxing the arena for
-                    # the slot's whole lifetime
-                    alloc.release([reserve])
-                    slot_reserve.pop(s.index, None)
+            with M.span("engine.cow_guard") as sp:
+                copies = 0
+                for s in active:
+                    j = s.length // bs
+                    old = int(tables[s.index][j])
+                    reserve = slot_reserve.get(s.index)
+                    pool = alloc.ensure_writable(
+                        tables[s.index], j, pool, reserve=reserve)
+                    new = int(tables[s.index][j])
+                    if new != old:
+                        # the ownership list must track the swap: the
+                        # shared block's ref was dropped by
+                        # ensure_writable; the private copy is released
+                        # at finish instead
+                        row = slot_blocks[s.index]
+                        row[row.index(old)] = new
+                        slot_reserve.pop(s.index, None)
+                        copies += 1
+                    elif reserve is not None:
+                        # first guarded decode tick and no copy was
+                        # needed: every shared block sits strictly below
+                        # the write frontier and the frontier block is
+                        # now exclusively ours, so COW can never fire for
+                        # this slot again — return the reserve instead of
+                        # taxing the arena for the slot's whole lifetime
+                        alloc.release([reserve])
+                        slot_reserve.pop(s.index, None)
 
-            # the decode step runs over *every* slot row; slots still
-            # mid-chunked-prefill must not have their real blocks
-            # stomped by the inactive-row write at position 0, so their
-            # table rows are masked to the scratch block for the burst
-            decode_tables = tables
-            if sched.prefilling:
-                decode_tables = tables.copy()
-                decode_tables[list(sched.prefilling)] = alloc.scratch
+                # the decode step runs over *every* slot row; slots still
+                # mid-chunked-prefill must not have their real blocks
+                # stomped by the inactive-row write at position 0, so
+                # their table rows are masked to the scratch block for
+                # the burst
+                decode_tables = tables
+                if sched.prefilling:
+                    decode_tables = tables.copy()
+                    decode_tables[list(sched.prefilling)] = alloc.scratch
+                sp.set_metadata(copies=copies)
             self.metrics.gauge("tick.free_blocks", alloc.n_free)
+            # KV blocks reserved (not free) against blocks holding a
+            # written position, at the burst's first tick; the arena
+            # does not change inside a burst
+            reserved = alloc.n_blocks - alloc.n_free
+            held = prefix.blocks()
+            for s in active:
+                held.update(tables[s.index, :s.length // bs + 1].tolist())
+            for s in sched.prefilling.values():
+                held.update(tables[s.index, :-(-s.prefilled // bs)].tolist())
+            lengths = [s.length for s in active]
+            ticks0 = tick_state["ticks"]
             new_pool = self._decode_burst(sched, pool, state, tick_state,
                                           max_ticks, max_burst,
                                           tables=decode_tables)
             if new_pool is None:
-                feed.wait(sched, clock)     # future arrivals / live inbox
+                with M.span("engine.wait"):
+                    feed.wait(sched, clock)  # future arrivals / live inbox
                 continue
             pool = new_pool
+            ticks = tick_state["ticks"] - ticks0
+            used = block_ticks_used(len(held), lengths, bs, ticks)
+            m = self.metrics
+            m.count("kv.block_ticks_reserved", ticks * reserved)
+            m.count("kv.block_ticks_used", used[0])
+            m.gauge("kv.blocks_reserved", reserved)
+            m.gauge("kv.blocks_used", used[1])
             for s in active:
                 release_if_finished(s)
 

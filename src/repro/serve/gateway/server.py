@@ -33,6 +33,7 @@ from typing import Optional
 from repro.serve.gateway import protocol as P
 from repro.serve.gateway.protocol import (GenerateRequest, ProtocolError,
                                           parse_request)
+from repro.serve.metrics import span
 
 
 class EngineBridge:
@@ -158,6 +159,7 @@ class EngineBridge:
                    else self._channels.get(uid))
         if channel is not None:
             channel.put_nowait(ev)
+            self.engine.metrics.count("gateway.events_delivered")
 
     def _fail_all(self, exc: BaseException) -> None:
         """Engine thread died: every in-flight channel gets a terminal
@@ -259,9 +261,11 @@ class Gateway:
     async def _generate(self, writer: asyncio.StreamWriter,
                         body: bytes) -> None:
         try:
-            greq = parse_request(json.loads(body.decode("utf-8")),
-                                 self.engine.cfg.vocab)
-            uid, channel = self.bridge.submit(greq)
+            with span("gateway.parse") as sp:
+                greq = parse_request(json.loads(body.decode("utf-8")),
+                                     self.engine.cfg.vocab)
+                uid, channel = self.bridge.submit(greq)
+                sp.set_metadata(uid=uid)
         except (ProtocolError, UnicodeDecodeError,
                 json.JSONDecodeError) as exc:
             await self._json(writer, 400, P.error_event(str(exc)))
@@ -282,7 +286,10 @@ class Gateway:
                      b"Content-Type: application/x-ndjson\r\n"
                      b"Connection: close\r\n\r\n")
         async for ev in self.bridge.events(uid, channel):
-            writer.write(json.dumps(ev).encode("utf-8") + b"\n")
+            # the span covers the encode and the buffer write the loop
+            # thread does holding the interpreter lock, not the drain
+            with span("gateway.write", uid=uid, event=ev["event"]):
+                writer.write(json.dumps(ev).encode("utf-8") + b"\n")
             await writer.drain()
 
     @staticmethod

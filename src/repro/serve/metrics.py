@@ -1,4 +1,5 @@
-"""Per-stage serving observability: ring-buffer series + percentiles.
+"""Per-stage serving observability: ring-buffer series, counters and
+profiler spans.
 
 The engine tick loop is the only place that sees every stage of a
 request's life — queue wait, prefill, decode — and every tick-level
@@ -11,6 +12,10 @@ JSON-safe dict. The gateway's ``/metrics`` endpoint and
 latency visibility is the same surface everywhere (deepsparse's
 ``_TextGenerationTimings`` per-stage timers are the model).
 
+:func:`span` marks what a thread is doing in the JAX profiler's host
+trace, on the device trace's clock; with the profiler off it records
+nothing.
+
 Pure host-side numpy — recording must never touch the jitted hot loop's
 device streams.
 """
@@ -19,14 +24,20 @@ from __future__ import annotations
 from typing import Iterable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-# the canonical per-request stage series, milliseconds (recorded by the
-# engine for every Finished request; names are part of the wire schema)
-REQUEST_STAGES = ("request.queue_ms", "request.prefill_ms",
-                  "request.decode_ms", "request.total_ms")
-# per-tick gauges (recorded each decode tick / loop iteration)
-TICK_GAUGES = ("tick.active_slots", "tick.prefill_backlog",
-               "tick.free_blocks", "tick.tokens_per_s")
+
+def span(name: str, **args) -> TraceAnnotation:
+    """A host span in the profiler's trace, used as a context manager.
+
+    ``name`` starts with the role of the thread that opens it
+    (``engine.`` or ``gateway.``); ``args`` come back as the event's
+    stats, and ``set_metadata(**args)`` on the entered span adds those
+    known only at its end. About a microsecond when no trace is being
+    taken: open one per burst, chunk or request, never per token or
+    slot on the engine thread.
+    """
+    return TraceAnnotation(name, **args)
 
 
 class RingBuffer:
@@ -87,14 +98,6 @@ class MetricsRegistry:
 
     # ---------------------------------------------------------- export
 
-    def percentiles(self, name: str,
-                    p: Iterable[int] = (50, 99)) -> dict:
-        buf = self.series.get(name)
-        if buf is None or not len(buf):
-            return {f"p{q}": 0.0 for q in p}
-        vals = buf.values()
-        return {f"p{q}": float(np.percentile(vals, q)) for q in p}
-
     def summary(self) -> dict:
         """JSON-safe digest of every series/counter/gauge."""
         out: dict = {"series": {}, "counters": dict(self.counters),
@@ -112,11 +115,6 @@ class MetricsRegistry:
                                for q in self.pcts})
             out["series"][name] = digest
         return out
-
-    def reset(self) -> None:
-        self.series.clear()
-        self.counters.clear()
-        self.gauges.clear()
 
 
 # --------------------------------------------------------- request stages

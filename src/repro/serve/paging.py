@@ -17,6 +17,9 @@ between ``scheduler`` (host) and ``batching`` (device).
   strictly before the last prompt token are shared, so every writer owns
   its tail block and at least one prompt token is always prefilled (the
   sampled-first-token logits come from the writer's own compute).
+- :func:`block_ticks_used` — blocks holding written positions, summed
+  over the ticks of a decode burst (the ``kv.block_ticks_used``
+  counter, against ``kv.block_ticks_reserved``).
 - ``make_paged_prefill_step`` / ``make_paged_decode_step`` — the jitted
   steps threading per-request block tables through
   ``transformer.forward`` the same way the vector ``cache_index`` is.
@@ -26,6 +29,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import transformer as T
 from repro.models.specs import ModelConfig
@@ -172,11 +176,35 @@ class PrefixCache:
         self.allocator.retain(blocks)
         self._entries[prefix_id] = (tuple(prompt[:n]), blocks)
 
+    def blocks(self) -> set:
+        """Every block a cached prefix holds a reference to."""
+        return {b for _, blocks in self._entries.values() for b in blocks}
+
     def drop_all(self) -> None:
         """Release every cached prefix (end of a serving run)."""
         for _, blocks in self._entries.values():
             self.allocator.release(blocks)
         self._entries.clear()
+
+
+def block_ticks_used(held: int, lengths, block_size: int,
+                     ticks: int) -> tuple:
+    """Blocks holding a written position over a decode burst of
+    ``ticks`` ticks: ``(sum over the ticks, count at the last tick)``.
+
+    ``held`` is the number of distinct blocks written to once the
+    burst's first tick has written, and ``lengths`` the decoding slots'
+    lengths before it. Tick ``k`` writes position ``length + k`` of each
+    slot, so a slot adds a block whenever that position starts one. The
+    block a slot writes into is its own (copy-on-write makes it so
+    before the burst), so no added block is counted twice.
+    """
+    if not ticks:
+        return 0, held
+    lens = np.asarray(lengths, np.int64)
+    k = np.arange(ticks, dtype=np.int64)[:, None]
+    grown = ((lens + k) // block_size - lens // block_size).sum(axis=1)
+    return int(ticks * held + grown.sum()), int(held + grown[-1])
 
 
 # ------------------------------------------------------------ jitted steps
