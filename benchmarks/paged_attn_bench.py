@@ -57,14 +57,15 @@ def kernel_agreement(B=4, M=4, bs=8, n_kv=2, n_q=4, D=16, seed=3):
     rng = np.random.default_rng(seed)
     nb = B * M
     q = jnp.asarray(rng.standard_normal((B, 1, n_q, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((nb + 1, bs, n_kv, D)),
+    k = jnp.asarray(rng.standard_normal((nb + 1, bs, n_kv * D)),
                     jnp.float32)
-    v = jnp.asarray(rng.standard_normal((nb + 1, bs, n_kv, D)),
+    v = jnp.asarray(rng.standard_normal((nb + 1, bs, n_kv * D)),
                     jnp.float32)
     tables = jnp.asarray(rng.permutation(nb).reshape(B, M), jnp.int32)
     lengths = jnp.asarray(rng.integers(1, M * bs + 1, (B,)), jnp.int32)
-    out = paged_attention_decode(q, k, v, tables, lengths, interpret=True)
-    ref = paged_attention_ref(q[:, 0], k, v, tables, lengths)[:, None]
+    out = paged_attention_decode(q, k, v, tables, lengths, n_kv,
+                                 interpret=True)
+    ref = paged_attention_ref(q[:, 0], k, v, tables, lengths, n_kv)[:, None]
     return float(jnp.abs(out - ref).max())
 
 

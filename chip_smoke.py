@@ -339,7 +339,7 @@ def kernel_oracles(sc: SmokeConfig, cfg, m: int = 16) -> dict:
         out[f"block_sparse_int8_{K}x{N}"] = _rel_err(y, ref)
     attn = cfg.pattern[0].mixer
     B, bs, blocks = sc.max_slots, sc.block_size, sc.max_seq // sc.block_size
-    arena = (B * blocks + 1, bs, attn.n_kv, attn.head_dim)
+    arena = (B * blocks + 1, bs, attn.n_kv * attn.head_dim)
     q = jnp.asarray(rng.standard_normal((B, 1, attn.n_q, attn.head_dim)),
                     jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal(arena), jnp.bfloat16)
@@ -347,8 +347,8 @@ def kernel_oracles(sc: SmokeConfig, cfg, m: int = 16) -> dict:
     tables = jnp.asarray(rng.permutation(B * blocks).reshape(B, blocks),
                          jnp.int32)
     lengths = jnp.asarray(rng.integers(1, sc.max_seq + 1, B), jnp.int32)
-    y = paged_attention_decode(q, k, v, tables, lengths)
-    ref = paged_attention_ref(q[:, 0], k, v, tables, lengths)
+    y = paged_attention_decode(q, k, v, tables, lengths, attn.n_kv)
+    ref = paged_attention_ref(q[:, 0], k, v, tables, lengths, attn.n_kv)
     out[f"paged_attention_{attn.n_q}q{attn.n_kv}kv"] = _rel_err(y[:, 0], ref)
     return out
 

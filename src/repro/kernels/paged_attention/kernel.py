@@ -17,12 +17,14 @@ entry is clamped so the revisit-detection DMA elides the copy), and the
 tail block masks columns ``>= length`` to -inf before the running max.
 
 Layout: the TPU lowering wants the last two dimensions of every block
-to be (8, 128)-divisible or whole. The arena is viewed as
-``(n_blocks + 1, block_size, n_kv * D)`` — a free reshape of the
-contiguous ``(.., n_kv, D)`` tail — so one KV head's block is the
-``(block_size, D)`` tile at lane offset ``h * D`` (a strided DMA), and
-the query as ``(B, n_kv, group, D)`` so a head-group block spans its
-whole ``(group, D)`` tail. Neither depends on the GQA group size.
+to be (8, 128)-divisible or whole. The arena is stored as
+``(n_blocks + 1, block_size, n_kv * D)`` (``init_paged_attention_cache``),
+so one KV head's block is the ``(block_size, D)`` tile at lane offset
+``h * D`` (a strided DMA), and the query is viewed as
+``(B, n_kv, group, D)`` so a head-group block spans its whole
+``(group, D)`` tail. Neither depends on the GQA group size. (A
+``(.., n_kv, D)`` arena viewed this way is not a free reshape on TPU:
+its tiles lie on ``(n_kv, D)``, and the view is a full relayout.)
 """
 from __future__ import annotations
 
@@ -80,12 +82,13 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
                     block_tables: jax.Array, lengths: jax.Array, *,
-                    interpret: bool = False) -> jax.Array:
+                    n_kv: int, interpret: bool = False) -> jax.Array:
     """Decode attention over a paged KV arena, gathering inside the kernel.
 
     q: (B, n_q, D) — one query token per sequence;
-    k_arena, v_arena: (n_blocks + 1, block_size, n_kv, D) — the shared
-    paged pool (last block is the allocator's scratch block);
+    k_arena, v_arena: (n_blocks + 1, block_size, n_kv * D) — the shared
+    paged pool (last block is the allocator's scratch block), read in
+    place;
     block_tables: (B, max_blocks) int32 — arena block per logical block;
     lengths: (B,) int32 — valid KV positions per sequence (entries past
     ``lengths[b]`` are masked; rows whose tables point at scratch simply
@@ -93,7 +96,8 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
     Returns (B, n_q, D).
     """
     B, n_q, D = q.shape
-    block_size, n_kv = k_arena.shape[1], k_arena.shape[2]
+    block_size = k_arena.shape[1]
+    assert k_arena.shape[2] == n_kv * D, (k_arena.shape, n_kv, D)
     max_blocks = block_tables.shape[1]
     group = n_q // n_kv
     scale = 1.0 / math.sqrt(D)
@@ -109,9 +113,6 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
     def q_index(b, h, j, tbl, lens):
         return (b, h, 0, 0)
 
-    n_rows = k_arena.shape[0]
-    k_view = k_arena.reshape(n_rows, block_size, n_kv * D)
-    v_view = v_arena.reshape(n_rows, block_size, n_kv * D)
     kernel = functools.partial(_kernel, scale=scale, block_size=block_size,
                                max_blocks=max_blocks)
     out = pl.pallas_call(
@@ -134,5 +135,5 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, group, D), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(block_tables, lengths, q.reshape(B, n_kv, group, D), k_view, v_view)
+    )(block_tables, lengths, q.reshape(B, n_kv, group, D), k_arena, v_arena)
     return out.reshape(B, n_q, D)

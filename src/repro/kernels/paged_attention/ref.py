@@ -16,11 +16,11 @@ import jax.numpy as jnp
 
 def paged_attention_ref(q: jax.Array, k_arena: jax.Array,
                         v_arena: jax.Array, block_tables: jax.Array,
-                        lengths: jax.Array) -> jax.Array:
-    """q: (B, n_q, D); arenas (n_blocks + 1, bs, n_kv, D);
+                        lengths: jax.Array, n_kv: int) -> jax.Array:
+    """q: (B, n_q, D); arenas (n_blocks + 1, bs, n_kv * D);
     block_tables (B, max_blocks); lengths (B,). Returns (B, n_q, D)."""
     B, n_q, D = q.shape
-    bs, n_kv = k_arena.shape[1], k_arena.shape[2]
+    bs = k_arena.shape[1]
     M = block_tables.shape[1]
     group = n_q // n_kv
     scale = 1.0 / math.sqrt(D)

@@ -149,36 +149,41 @@ def paged_write(cache_kv: jax.Array, new: jax.Array,
                 block_tables: jax.Array, start: jax.Array,
                 n_valid: Optional[jax.Array] = None) -> jax.Array:
     """Scatter ``new`` (B, S, n_kv, D) K/V rows into a paged arena
-    ``cache_kv`` (n_blocks, block_size, n_kv, D) at logical positions
+    ``cache_kv`` (n_blocks, block_size, n_kv * D) at logical positions
     ``start[b] + [0, S)`` of each sequence's ``block_tables`` row.
 
-    Rows past ``n_valid[b]`` (right-padded prefill positions) are
-    redirected into the arena's last block — the reserved scratch block
-    the allocator never hands out — so padding never corrupts a live
-    block. jit-safe: ``start``/``n_valid`` may be traced.
+    The arena's flat ``(n_blocks * block_size, n_kv * D)`` view keeps
+    its tiled layout, so the scatter updates it in place (no relayout of
+    the arena around it). Rows past ``n_valid[b]`` (right-padded prefill
+    positions) are redirected into the arena's last block — the reserved
+    scratch block the allocator never hands out — so padding never
+    corrupts a live block. jit-safe: ``start``/``n_valid`` may be traced.
     """
-    nb, bs = cache_kv.shape[0], cache_kv.shape[1]
+    nb, bs, width = cache_kv.shape
     B, S = new.shape[0], new.shape[1]
-    flat = cache_kv.reshape(nb * bs, *cache_kv.shape[2:])
+    flat = cache_kv.reshape(nb * bs, width)
     pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     rows = _paged_rows(block_tables, pos, bs)
     if n_valid is not None:
         ok = jnp.arange(S, dtype=jnp.int32)[None, :] < n_valid[:, None]
         rows = jnp.where(ok, rows, nb * bs - 1)     # scratch block
     flat = flat.at[rows.reshape(-1)].set(
-        new.astype(flat.dtype).reshape(B * S, *new.shape[2:]))
+        new.astype(flat.dtype).reshape(B * S, width))
     return flat.reshape(cache_kv.shape)
 
 
-def paged_gather(cache_kv: jax.Array, block_tables: jax.Array) -> jax.Array:
+def paged_gather(cache_kv: jax.Array, block_tables: jax.Array,
+                 n_kv: int) -> jax.Array:
     """Gather each sequence's logical KV view (B, max_blocks*block_size,
-    n_kv, D) from the paged arena via its block table."""
-    nb, bs = cache_kv.shape[0], cache_kv.shape[1]
-    flat = cache_kv.reshape(nb * bs, *cache_kv.shape[2:])
+    n_kv, D) from the paged arena (n_blocks, block_size, n_kv * D) via
+    its block table."""
+    nb, bs, width = cache_kv.shape
+    flat = cache_kv.reshape(nb * bs, width)
     B, M = block_tables.shape
     rows = (block_tables[:, :, None] * bs
             + jnp.arange(bs, dtype=jnp.int32)[None, None, :])
-    return flat[rows.reshape(B, M * bs)]
+    return flat[rows.reshape(B, M * bs)].reshape(B, M * bs, n_kv,
+                                                 width // n_kv)
 
 
 def apply_attention(params: dict, spec: AttentionSpec, x: jax.Array,
@@ -188,7 +193,7 @@ def apply_attention(params: dict, spec: AttentionSpec, x: jax.Array,
                     n_valid: Optional[jax.Array] = None,
                     paged_kernel: bool = False):
     """Returns (out, new_cache). cache: {'k','v': (B, S_max, n_kv, D)},
-    or a paged arena {'k','v': (n_blocks, block_size, n_kv, D)} when
+    or a paged arena {'k','v': (n_blocks, block_size, n_kv * D)} when
     ``block_tables`` (B, max_blocks) maps each sequence's logical blocks
     onto arena blocks; ``n_valid`` (B,) masks right-padded positions of
     a padded (chunked) prefill. ``paged_kernel`` selects the fused Pallas
@@ -253,12 +258,12 @@ def apply_attention(params: dict, spec: AttentionSpec, x: jax.Array,
             nv1 = (n_valid if n_valid is not None
                    else jnp.full((x.shape[0],), 1, jnp.int32))
             out = paged_attention_decode(q, ck, cv, block_tables,
-                                         ci + nv1)
+                                         ci + nv1, spec.n_kv)
             tap("attn_o", out, channel_axes=(-2, -1))
             y = jnp.einsum("bshe,hed->bsd", out, params["o"].astype(dtype))
             return hint(y, "batch", "seq", "embed"), new_cache
-        kview = paged_gather(ck, block_tables)
-        vview = paged_gather(cv, block_tables)
+        kview = paged_gather(ck, block_tables, spec.n_kv)
+        vview = paged_gather(cv, block_tables, spec.n_kv)
         T_kv = kview.shape[1]
         kv_pos = jnp.broadcast_to(
             jnp.arange(T_kv, dtype=jnp.int32)[None, :],
@@ -342,8 +347,10 @@ def init_paged_attention_cache(n_blocks: int, block_size: int,
                                spec: AttentionSpec,
                                dtype=jnp.bfloat16) -> dict:
     """A paged KV arena: ``n_blocks`` fixed-size blocks shared by every
-    sequence (the last block is the padding scratch block)."""
-    shape = (n_blocks, block_size, spec.n_kv, spec.head_dim)
+    sequence (the last block is the padding scratch block). Each block
+    is stored ``(block_size, n_kv * D)``, the layout the paged-attention
+    kernel reads, so neither it nor ``paged_write`` relayouts the arena."""
+    shape = (n_blocks, block_size, spec.n_kv * spec.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 

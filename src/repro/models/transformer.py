@@ -106,9 +106,13 @@ def init_cache_pool(cfg: ModelConfig, max_slots: int, max_seq: int,
 def init_paged_pool(cfg: ModelConfig, n_blocks: int, block_size: int,
                     dtype=jnp.bfloat16):
     """A paged KV pool: per attention layer, one ``(n_blocks + 1,
-    block_size, n_kv, D)`` arena of fixed-size KV blocks shared by every
+    block_size, n_kv * D)`` arena of fixed-size KV blocks shared by every
     request through per-request block tables (``block_tables`` in
     ``forward``), instead of one contiguous ``max_seq`` region per slot.
+    That is the layout the paged-attention kernel reads and the one the
+    steps scatter into, so a step that is given the pool donated updates
+    each arena in place (an arena stored ``(.., n_kv, D)`` would be
+    relaid out on TPU, several times a step).
 
     The extra last block (index ``n_blocks``) is the *scratch* block:
     right-padded prefill positions and inactive decode slots write there

@@ -61,6 +61,15 @@ from repro.serve.paging import (BlockAllocator, PrefixCache,
 from repro.serve.scheduler import Scheduler
 
 
+def pool_donation() -> tuple:
+    """The arguments of the engine's jitted steps that are donated: the
+    pool (argument 1) on an accelerator, so each KV arena is updated in
+    place. The CPU backend, which runs the tests and the benchmark's CPU
+    rehearsals, keeps the pool a step was given alive: those plant
+    faulty steps that hand it back."""
+    return () if jax.default_backend() == "cpu" else (1,)
+
+
 @dataclasses.dataclass
 class ServeStats:
     ticks: int
@@ -215,13 +224,16 @@ class ContinuousEngine:
         mlp_apply = (make_sparse_mlp_apply(packed, serve.group_experts,
                                            serve.ragged_moe, serve.quant)
                      if packed else None)
+        # the pool goes donated (``pool_donation``) to the paged
+        # prefill and to the decode step of both backends: the callers
+        # thread the returned pool and never touch the one they passed
+        donate = pool_donation()
         if serve.paged:
             self._prefill = jax.jit(make_paged_prefill_step(
-                cfg, serve.compute_dtype, mlp_apply))
+                cfg, serve.compute_dtype, mlp_apply), donate_argnums=donate)
             decode = make_paged_decode_step(
                 cfg, serve.compute_dtype, mlp_apply,
                 paged_kernel=serve.paged_kernel)
-            self._copy_block = jax.jit(T.copy_pool_block)
         else:
             self._prefill = jax.jit(make_prefill_step(
                 cfg, serve.compute_dtype, mlp_apply))
@@ -238,7 +250,7 @@ class ContinuousEngine:
             logits, pool = decode(params, pool, tokens, lengths, *tables)
             keys = jax.vmap(jax.random.fold_in)(bases, counts)
             return sample_tokens(logits, keys, temps, cfg.vocab), pool
-        self._decode_sample = jax.jit(decode_sample)
+        self._decode_sample = jax.jit(decode_sample, donate_argnums=donate)
 
         def first_sample(logits_row, base, temp):
             key = jax.random.fold_in(base, 0)

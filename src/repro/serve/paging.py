@@ -1,8 +1,9 @@
 """Paged KV pool bookkeeping: block allocator, copy-on-write, prefix
 sharing, and the jitted paged prefill/decode step factories.
 
-The device arena is one ``(n_blocks + 1, block_size, n_kv, D)`` array
-per layer (``transformer.init_paged_pool``); everything in this module
+The device arena is one ``(n_blocks + 1, block_size, n_kv * D)`` array
+per layer for K and one for V (``transformer.init_paged_pool``), the
+layout the paged-attention kernel reads; everything in this module
 except the step factories is pure host-side state, mirroring the split
 between ``scheduler`` (host) and ``batching`` (device).
 

@@ -293,11 +293,16 @@ def test_flash_attention_kernel(hq, hkv, dtype):
 
 def _paged_case(hq, hkv, dtype, B=4, M=4, bs=8, D=16, seed=7):
     """Random paged decode problem: a shuffled arena (so physical order
-    never matches logical order), ragged lengths, one query per row."""
+    never matches logical order), ragged lengths, one query per row. The
+    arenas are drawn ``(nb + 1, bs, hkv, D)`` and stored as the pool
+    stores them, ``(nb + 1, bs, hkv * D)``."""
     rng = np.random.default_rng(seed)
     nb = B * M
-    k_arena = jnp.asarray(rng.normal(size=(nb + 1, bs, hkv, D)), dtype)
-    v_arena = jnp.asarray(rng.normal(size=(nb + 1, bs, hkv, D)), dtype)
+    shape = (nb + 1, bs, hkv, D)
+    k_arena = jnp.asarray(rng.normal(size=shape), dtype).reshape(
+        nb + 1, bs, hkv * D)
+    v_arena = jnp.asarray(rng.normal(size=shape), dtype).reshape(
+        nb + 1, bs, hkv * D)
     tables = jnp.asarray(rng.permutation(nb).reshape(B, M), jnp.int32)
     lengths = jnp.asarray(rng.integers(1, M * bs + 1, (B,)), jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, 1, hq, D)), dtype)
@@ -308,11 +313,12 @@ def _paged_case(hq, hkv, dtype, B=4, M=4, bs=8, D=16, seed=7):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_kernel(hq, hkv, dtype):
     q, ka, va, tables, lengths = _paged_case(hq, hkv, dtype)
-    o_k = paged_attention_decode(q, ka, va, tables, lengths,
+    o_k = paged_attention_decode(q, ka, va, tables, lengths, hkv,
                                  interpret=True)
     o_r = paged_attention_ref(q[:, 0].astype(jnp.float32),
                               ka.astype(jnp.float32),
-                              va.astype(jnp.float32), tables, lengths)
+                              va.astype(jnp.float32), tables, lengths,
+                              hkv)
     err = jnp.abs(o_k[:, 0].astype(jnp.float32) - o_r).max()
     assert float(err) < (5e-6 if dtype == jnp.float32 else 3e-2)
 
@@ -321,11 +327,12 @@ def test_paged_attention_matches_gather_path():
     """The kernel must agree with the serving gather path itself
     (paged_gather + _dense_attention with the decode-time length mask),
     not just the standalone oracle."""
-    q, ka, va, tables, lengths = _paged_case(4, 2, jnp.float32, seed=11)
-    o_k = paged_attention_decode(q, ka, va, tables, lengths,
+    hkv = 2
+    q, ka, va, tables, lengths = _paged_case(4, hkv, jnp.float32, seed=11)
+    o_k = paged_attention_decode(q, ka, va, tables, lengths, hkv,
                                  interpret=True)
-    kview = paged_gather(ka, tables)
-    vview = paged_gather(va, tables)
+    kview = paged_gather(ka, tables, hkv)
+    vview = paged_gather(va, tables, hkv)
     T_kv = kview.shape[1]
     kv_pos = jnp.broadcast_to(jnp.arange(T_kv, dtype=jnp.int32)[None, :],
                               (q.shape[0], T_kv))
@@ -341,14 +348,15 @@ def test_paged_attention_scratch_masked_slot():
     masked to the scratch block and length clamped to 1 (its output is
     discarded): the kernel must stay finite for it and exact for the
     live rows."""
-    q, ka, va, tables, lengths = _paged_case(4, 2, jnp.float32, seed=13)
+    hkv = 2
+    q, ka, va, tables, lengths = _paged_case(4, hkv, jnp.float32, seed=13)
     scratch = ka.shape[0] - 1
     tables = tables.at[1].set(scratch)
     lengths = lengths.at[1].set(1)
-    o_k = paged_attention_decode(q, ka, va, tables, lengths,
+    o_k = paged_attention_decode(q, ka, va, tables, lengths, hkv,
                                  interpret=True)
     assert bool(jnp.all(jnp.isfinite(o_k)))
-    o_r = paged_attention_ref(q[:, 0], ka, va, tables, lengths)
+    o_r = paged_attention_ref(q[:, 0], ka, va, tables, lengths, hkv)
     live = np.array([0, 2, 3])
     err = jnp.abs(o_k[live, 0] - o_r[live]).max()
     assert float(err) < TOL[jnp.float32]
@@ -358,13 +366,14 @@ def test_paged_attention_shared_prefix_tables():
     """Prefix sharing maps the same physical blocks into several rows'
     tables: rows with identical tables, lengths, and queries must
     produce identical outputs, and both must match the oracle."""
-    q, ka, va, tables, lengths = _paged_case(4, 2, jnp.float32, seed=17)
+    hkv = 2
+    q, ka, va, tables, lengths = _paged_case(4, hkv, jnp.float32, seed=17)
     tables = tables.at[2].set(tables[0])        # full shared view
     lengths = lengths.at[2].set(lengths[0])
     q = q.at[2].set(q[0])
     tables = tables.at[3, :2].set(tables[1, :2])  # shared 2-block prefix
-    o_k = paged_attention_decode(q, ka, va, tables, lengths,
+    o_k = paged_attention_decode(q, ka, va, tables, lengths, hkv,
                                  interpret=True)
     np.testing.assert_array_equal(np.asarray(o_k[2]), np.asarray(o_k[0]))
-    o_r = paged_attention_ref(q[:, 0], ka, va, tables, lengths)
+    o_r = paged_attention_ref(q[:, 0], ka, va, tables, lengths, hkv)
     assert float(jnp.abs(o_k[:, 0] - o_r).max()) < TOL[jnp.float32]

@@ -12,9 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.specs import (AttentionSpec, LayerSpec, MLPSpec,
                                 ModelConfig)
+from repro.serve import batching
 from repro.serve.batching import ContinuousEngine
 from repro.serve.config import ServeConfig
 from repro.serve.engine import Engine
@@ -252,6 +254,75 @@ def test_paged_kernel_prefix_sharing_identical(served):
         ServeConfig(**serve, paged_kernel=True)).run(reqs())
     assert _tokens(got) == _tokens(ref)
     assert stats.prompt_blocks_shared >= 4 and stats.rejected == 0
+
+
+def test_paged_arena_round_trip_matches_4d_layout():
+    """``paged_write`` then ``paged_gather`` on the ``(n_blocks + 1, bs,
+    n_kv * D)`` arena give, element for element, what a ``(n_blocks + 1,
+    bs, n_kv, D)`` arena gives under the same block tables, padding
+    rows redirected to the scratch block."""
+    rng = np.random.default_rng(5)
+    nb, bs, n_kv, D, B, S, M = 12, 4, 2, 16, 3, 6, 4
+    arena4 = rng.normal(size=(nb + 1, bs, n_kv, D)).astype(np.float32)
+    new = rng.normal(size=(B, S, n_kv, D)).astype(np.float32)
+    tables = rng.permutation(nb)[:B * M].reshape(B, M).astype(np.int32)
+    start = np.array([0, 5, 9], np.int32)
+    n_valid = np.array([6, 2, 4], np.int32)
+    # the (.., n_kv, D) layout written out: each position's row, scatter
+    pos = start[:, None] + np.arange(S)[None, :]
+    rows = tables[np.arange(B)[:, None], pos // bs] * bs + pos % bs
+    rows = np.where(np.arange(S)[None, :] < n_valid[:, None], rows,
+                    (nb + 1) * bs - 1)
+    flat4 = arena4.reshape(-1, n_kv, D).copy()
+    flat4[rows.reshape(-1)] = new.reshape(B * S, n_kv, D)
+    view_rows = (tables[:, :, None] * bs + np.arange(bs)).reshape(B, -1)
+    want = flat4[view_rows]
+
+    arena3 = jnp.asarray(arena4.reshape(nb + 1, bs, n_kv * D))
+    written = L.paged_write(arena3, jnp.asarray(new), jnp.asarray(tables),
+                            jnp.asarray(start), jnp.asarray(n_valid))
+    assert written.shape == arena3.shape
+    np.testing.assert_array_equal(np.asarray(written).reshape(flat4.shape),
+                                  flat4)
+    got = L.paged_gather(written, jnp.asarray(tables), n_kv)
+    assert got.shape == (B, M * bs, n_kv, D)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("extra", [
+    {}, {"block_size": 8, "prefill_chunk": 8},
+    {"block_size": 8, "prefill_chunk": 8, "paged_kernel": True}],
+    ids=["contiguous", "paged", "paged_kernel"])
+def test_donated_pool_served_twice_token_identical(served, monkeypatch,
+                                                   extra):
+    """With the pool donated to the steps, as on an accelerator, an
+    engine served twice stays token-identical to a fresh engine's run,
+    prefix sharing (copy-on-write) included, and every pool a donating
+    step was given is gone after it: nothing reads a donated buffer."""
+    params, cfg = served
+    serve = ServeConfig(**BASE, **extra)
+    rng = np.random.default_rng(1)
+    prefix = rng.integers(1, 256, (19,)).tolist()
+    reqs = lambda: _mixed_requests() + [  # noqa: E731
+        Request(uid=10 + i, prompt=prefix + [50 + i], max_new_tokens=6,
+                prefix_id="sys") for i in range(3)]
+    ref, _ = ContinuousEngine(params, cfg, serve).run(reqs())
+
+    monkeypatch.setattr(batching, "pool_donation", lambda: (1,))
+    eng = ContinuousEngine(params, cfg, serve)
+    given = []
+    donating = ["_decode_sample"] + (["_prefill"] if serve.paged else [])
+    for name in donating:
+        def spy(params, pool, *a, _step=getattr(eng, name)):
+            given.append(pool)
+            return _step(params, pool, *a)
+        setattr(eng, name, spy)
+    for _ in range(2):
+        got, stats = eng.run(reqs())
+        assert _tokens(got) == _tokens(ref)
+        assert stats.rejected == 0
+    leaves = jax.tree.leaves(given)
+    assert leaves and all(leaf.is_deleted() for leaf in leaves)
 
 
 def test_cow_reserve_claimed_at_admission(served, monkeypatch):

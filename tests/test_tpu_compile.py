@@ -7,7 +7,10 @@ Widths: phi3-medium-14b (d 5120, d_ff 17920, 40 q / 10 kv heads, head
 dim 128) and qwen3-moe-30b-a3b (d 2048, expert d_ff 768, 128 experts,
 32 q / 4 kv heads). The topology is described inside a fixture, so
 only the worker that runs this file loads the TPU compiler."""
+import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,7 @@ from repro.kernels.paged_attention.ops import paged_attention_decode
 from repro.models.moe import ragged_rows_bound
 
 B = 128                              # plan tile (recipe default)
+RELAYOUT_OPS = {"copy", "copy-start", "reshape", "transpose"}
 
 
 @pytest.fixture(scope="module")
@@ -91,14 +95,79 @@ def test_paged_attention_compiles(one_chip, heads):
     D, bs, slots, max_blocks = 128, 16, 8, 16
     n_rows = slots * max_blocks + 1            # arena + scratch block
     q = _spec((slots, 1, n_q, D), jnp.bfloat16, one_chip)
-    arena = _spec((n_rows, bs, n_kv, D), jnp.bfloat16, one_chip)
+    arena = _spec((n_rows, bs, n_kv * D), jnp.bfloat16, one_chip)
     tables = _spec((slots, max_blocks), jnp.int32, one_chip)
     lengths = _spec((slots,), jnp.int32, one_chip)
     text = _compiled_text(
-        lambda q, k, v, t, n: paged_attention_decode(q, k, v, t, n,
+        lambda q, k, v, t, n: paged_attention_decode(q, k, v, t, n, n_kv,
                                                      interpret=False),
         q, arena, arena, tables, lengths)
     assert "tpu_custom_call" in text
+
+
+def _entry_ops(text: str):
+    """(opcode, largest result element count) of each instruction of
+    the compiled program's ENTRY computation."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([a-z][\w-]*)\(", line)
+        if not m:
+            continue
+        sizes = [math.prod(int(d) for d in dims.split(",") if d)
+                 for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+        yield m.group(2), max(sizes, default=0)
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_paged_step_updates_the_arena_in_place(one_chip, monkeypatch, step):
+    """The engine's paged decode-and-sample step and its paged prefill
+    step (a 256-token chunk) at phi3-medium widths (2 layers, 64 slots,
+    an 801-block arena of 128-token blocks, the fused decode kernel),
+    built as on the chip: the backend reads as the TPU, so the kernel
+    compiles and the pool is donated. Each K and V arena is then
+    scattered into in place: no copy, reshape or transpose of an arena's
+    size is left in the program, and the pool is aliased from input to
+    output in full."""
+    from repro.configs.phi3_medium_14b import config
+    from repro.models import transformer as T
+    from repro.serve.batching import ContinuousEngine
+    from repro.serve.config import ServeConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = config().replace(n_periods=2, scan_layers=False)
+    serve = ServeConfig(max_slots=64, max_seq=4096, block_size=128,
+                        n_blocks=800, paged_kernel=True, prefill_chunk=256)
+    on_chip = functools.partial(jax.tree.map, lambda t: _spec(
+        t.shape, t.dtype, one_chip))
+    params = on_chip(jax.eval_shape(
+        lambda: T.init_model(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    pool = on_chip(jax.eval_shape(lambda: T.init_paged_pool(
+        cfg, serve.arena_blocks, serve.block_size, jnp.bfloat16)))
+    eng = ContinuousEngine(params, cfg, serve)
+    i32 = functools.partial(_spec, dtype=jnp.int32, sharding=one_chip)
+    n, m = serve.max_slots, serve.blocks_per_seq
+    if step == "decode":
+        lowered = eng._decode_sample.lower(
+            params, pool, i32((n, 1)), i32((n,)),
+            _spec((n, 2), jnp.uint32, one_chip), i32((n,)),
+            _spec((n,), jnp.float32, one_chip), i32((n, m)))
+    else:
+        lowered = eng._prefill.lower(
+            params, pool, i32((1, serve.prefill_chunk)), i32((1, m)),
+            i32((1,)), i32((1,)))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    if step == "decode":
+        assert "tpu_custom_call" in text
+    arena = math.prod(pool[0]["attn"]["k"].shape)
+    assert arena == 801 * 128 * 10 * 128
+    relayouts = [(op, size) for op, size in _entry_ops(text)
+                 if size >= arena and op in RELAYOUT_OPS]
+    assert relayouts == []
+    pool_bytes = sum(t.size * t.dtype.itemsize
+                     for t in jax.tree.leaves(pool))
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
 # qwen3-moe expert widths: (K, N) of the up/gate and the down projection
