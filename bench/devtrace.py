@@ -10,6 +10,7 @@ tuples, so ``tests/bench`` checks it on a small recorded trace.
 """
 from __future__ import annotations
 
+import importlib
 import re
 import shutil
 import time
@@ -17,20 +18,22 @@ from pathlib import Path
 
 OPEN, CLOSE = "bench.trace_open", "bench.trace_close"
 
-# A Pallas kernel runs as a ``custom-call`` op, and both kernels of the
-# serving path are built from functions named ``_kernel``, so the trace
-# tells them apart by their result: the block-sparse matmul returns the
-# (rows, N) product, the paged-attention decode kernel a 4-D
-# (batch, kv heads, group, head_dim) block. A name or name scope that
-# says which kernel it is wins where the trace carries one.
-KERNELS = {
-    "block_sparse": re.compile(
-        r"blocksparse_matmul|block_sparse|"
-        r"= \w+\[\d+,\d+\](\{[^}]*\})? custom-call\("),
-    "paged_attention": re.compile(
-        r"_decode_jit|paged_attention|"
-        r"= \w+\[\d+,\d+,\d+,\d+\](\{[^}]*\})? custom-call\("),
-}
+
+def _kernel_patterns() -> dict:
+    """``TRACE`` of each ``bench/work`` module that declares one, by
+    module name in sorted order: the first that an op matches names its
+    kernel."""
+    out = {}
+    for path in sorted((Path(__file__).resolve().parent / "work")
+                       .glob("[!_]*.py")):
+        mod = importlib.import_module(f"bench.work.{path.stem}")
+        if hasattr(mod, "TRACE"):
+            out[path.stem] = mod.TRACE
+    return out
+
+
+KERNELS = _kernel_patterns()
+
 # The engine's jitted steps, by the name XLA gives their module.
 STEPS = {
     "decode": re.compile(r"decode_sample"),

@@ -24,7 +24,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import devtrace, layer_metrics, model, reference, traffic, work
+from bench import devtrace, layer_metrics, model, models, traffic
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -34,7 +34,8 @@ GB = 1e9
 
 def load_spec(workload: str, benchmark: dict) -> dict:
     """The cell's entry of ``BENCHMARK.json`` with its configuration,
-    traffic mix and cell files, and the metrics it reports."""
+    traffic mix and cell files, the model module its configuration
+    names, and the metrics it reports."""
     cells = {w["name"]: w for w in benchmark["workloads"]}
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
@@ -47,8 +48,9 @@ def load_spec(workload: str, benchmark: dict) -> dict:
     per_layer = [m for m in benchmark["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in names)]
-    return {"name": workload, "chips": w["chips"],
-            "conf": model.load_json("configs", w["config"]),
+    conf = model.load_json("configs", w["config"])
+    return {"name": workload, "chips": w["chips"], "conf": conf,
+            "model": models.load(conf),
             "mix": model.load_json("traffic", w["traffic"]),
             "cell": model.load_json("cells", workload),
             "end_to_end": e2e, "per_layer": per_layer}
@@ -265,15 +267,15 @@ def prepare(spec: dict, seed: int, *, cache: bool = True,
     import jax
     hooks = hooks or {}
     st = Served()
-    conf = spec["conf"]
+    conf, mod = spec["conf"], spec["model"]
     if cache:
         log(f"compile cache: {enable_compile_cache()}")
     st.compiles = CompileLog()
     st.devices = jax.devices()
     st.dev = st.devices[0]
 
-    cfg = model.program_config(conf)
-    dense = model.init_params(conf, seed)
+    cfg = mod.program_config(conf)
+    dense = mod.init_params(conf, seed)
     jax.block_until_ready(dense)
     from repro.core.pipeline import MosaicPipeline
     from repro.core.recipe import PruneRecipe
@@ -285,8 +287,7 @@ def prepare(spec: dict, seed: int, *, cache: bool = True,
     jax.block_until_ready(art.params)
     st.prune_s = time.monotonic() - t0
     t0 = time.monotonic()
-    st.selection = reference.extract_selection(dense, art.params,
-                                               recipe.block)
+    st.selection = mod.extract_selection(dense, art.params, recipe.block)
     log(f"pipeline: {st.prune_s:.3f} s; selection: "
         f"{time.monotonic() - t0:.3f} s; problems: "
         f"{len(st.selection['problems'])}")
@@ -294,7 +295,7 @@ def prepare(spec: dict, seed: int, *, cache: bool = True,
         art = hooks["artifact"](dense, cfg, recipe, art)
     del dense
     gc.collect()
-    st.structure = work.structure(art, recipe.block)
+    st.structure = mod.structure(art, recipe.block)
 
     from repro.serve.batching import ContinuousEngine
     from repro.serve.config import ServeConfig
@@ -455,16 +456,16 @@ def check(spec: dict, seed: int, selection: dict, records: list,
     beside its limit. ``on_gaps``, where given, receives each checked
     request's gaps (``bench/control.py`` keeps them)."""
     import jax
-    conf, cell = spec["conf"], spec["cell"]
+    conf, cell, mod = spec["conf"], spec["cell"], spec["model"]
     stream_bad = sum(r["status"] == "done" and r["tokens"] != r["end"]["tokens"]
                      for r in records)
     sample = _sample(records, seed, cell["check_requests"])
     t0 = time.monotonic()
-    dense = model.init_params(conf, seed)
+    dense = mod.init_params(conf, seed)
     jax.block_until_ready(dense)
     pad = min(serve.max_seq, -(-traffic.max_context(spec["mix"]) // 512) * 512)
-    gaps = reference.served_gaps(
-        model.shapes(conf), dense, selection,
+    gaps = mod.served_gaps(
+        conf, dense, selection,
         [(traffic.request(plan, r["i"])["tokens"], r["tokens"])
          for r in sample], pad_to=pad)
     widest, mean, n = _gap_stats(gaps)

@@ -5,8 +5,9 @@ A reader is ``read(ctx) -> float | None``. ``ctx`` holds the run's
 record: ``records`` (the load generator's per-request records),
 ``window`` (the measured window on ``time.monotonic``), ``recorder``
 (the engine's decode bursts and prefill chunks), ``structure`` (the
-served model's shapes, ``bench/work``), ``serve`` (the ``ServeConfig``),
-``prune_s``, ``trace`` (``bench/trace.py``'s reduction of the profiled
+served model's shapes, from its model module's ``structure``,
+``bench/models``), ``serve`` (the ``ServeConfig``), ``prune_s``,
+``trace`` (``bench/devtrace.py``'s reduction of the profiled
 part of the window) and ``device_kind``. A reader that finds nothing to
 read returns None, and the metric is left out of the line.
 """

@@ -1,20 +1,20 @@
-"""The plain reference and the comparison that decides ``correct``.
+"""The plain reference's building blocks, and the selection helpers.
 
 The reference imports nothing of the program. It is a float32 decoder
-in ``jax.numpy`` at ``highest`` matmul precision: RMSNorm, rotary
-positions (half-split, theta from the configuration), grouped-query
-causal attention with optional q/k/v biases, a SwiGLU MLP and an LM
-head (tied to the embedding where the configuration ties them). It
-runs on the benchmark's own dense weights (``model.init_params``, drawn
-again from the seed), masked by the *selection*: which heads, channels
-and weights the pruning kept, in the dense model's coordinates.
+in ``jax.numpy`` at ``highest`` matmul precision, composed per
+architecture by its model module (``bench/models/<module>.py``) from
+the blocks here: RMSNorm, rotary positions (half-split), grouped-query
+causal attention with optional q/k/v biases, the embedding of the
+checked requests and the gaps read at the LM head. It runs on the
+benchmark's own dense weights, drawn again from the seed, masked by the
+*selection*: which heads, channels and weights the pruning kept, in
+the dense model's coordinates.
 
-The selection is the one thing read from the program. It is taken at
-set-up by matching every pruned weight of the artifact against the
-dense weights (``extract_selection``), and the match is itself checked:
-each pruned weight that is not zero must equal the dense weight it
-came from, bit for bit, and kept heads and channels must keep their
-order. Every value the reference computes with is then its own.
+The selection is the one thing read from the program. A model module
+takes it at set-up by matching every pruned weight of the artifact
+against the dense weights, with the exact tile signatures and matchers
+here, and checks the match itself. Every value the reference computes
+with is then its own.
 """
 from __future__ import annotations
 
@@ -129,97 +129,11 @@ def _count_bad(p, d, idx, axis: int) -> int:
     return int(_bad(p, d, jnp.asarray(idx, jnp.int32), axis=axis))
 
 
-def extract_selection(dense: dict, pruned: dict, block: int) -> dict:
-    """What the pruning kept, in dense coordinates, per layer: packed
-    keep masks of every projection and the kept-head mask of the biases.
-    ``problems`` lists every way the artifact fails to be a selection
-    of the dense weights (empty when it is one)."""
-    problems, layers = [], []
-    for i, (db, pb) in enumerate(zip(dense["blocks"], pruned["blocks"])):
-        da, pa, dm, pm = db["attn"], pb["attn"], db["mlp"], pb["mlp"]
-        n_q = da["q"].shape[1]
-        bad, nz = _head_match(pa["q"], da["q"], pa["o"], da["o"])
-        heads = []
-        for j in range(bad.shape[0]):
-            hs = np.nonzero(bad[j] == 0)[0]
-            if nz[j] == 0 or len(hs) != 1:
-                problems.append(f"layer {i}: pruned head {j} matches "
-                                f"{len(hs)} dense heads")
-                hs = [0]
-            heads.append(int(hs[0]))
-        if heads != sorted(set(heads)):
-            problems.append(f"layer {i}: kept heads out of order {heads}")
-        sig = [(_tile_sigs(pm["up"], block), _tile_sigs(dm["up"], block)),
-               (_tile_sigs(pm["gate"], block), _tile_sigs(dm["gate"], block)),
-               (_tile_sigs(pm["down"].T, block),
-                _tile_sigs(dm["down"].T, block))]
-        chans = _match_columns([s[0] for s in sig], [s[1] for s in sig])
-        if (chans < 0).any():
-            problems.append(f"layer {i}: {(chans < 0).sum()} pruned "
-                            "channels match no dense channel")
-            chans = np.where(chans < 0, 0, chans)
-        if (np.diff(chans) <= 0).any():
-            problems.append(f"layer {i}: kept channels out of order")
-        ff = dm["up"].shape[1]
-        checks = {"q": (pa["q"], da["q"], heads, 1),
-                  "o": (pa["o"], da["o"], heads, 0),
-                  "k": (pa["k"], da["k"], np.arange(da["k"].shape[1]), 1),
-                  "v": (pa["v"], da["v"], np.arange(da["v"].shape[1]), 1),
-                  "gate": (pm["gate"], dm["gate"], chans, 1),
-                  "up": (pm["up"], dm["up"], chans, 1),
-                  "down": (pm["down"], dm["down"], chans, 0)}
-        masks = {}
-        for name, (p, d, idx, axis) in checks.items():
-            n = _count_bad(p, d, idx, axis)
-            if n:
-                problems.append(f"layer {i} {name}: {n} kept weights differ "
-                                "from the dense weights")
-            size = d.shape[axis]
-            masks[name] = _scatter_mask(p, idx, axis, size)
-        for name in ("q_bias", "k_bias", "v_bias"):
-            if name in da:
-                idx = heads if name == "q_bias" else np.arange(
-                    da[name].shape[0])
-                got = np.asarray(pa[name], np.float32)
-                want = np.asarray(da[name], np.float32)[np.asarray(idx)]
-                if got.shape != want.shape or (got != want).any():
-                    problems.append(f"layer {i} {name}: differs from the "
-                                    "dense bias of the kept heads")
-        head_keep = np.zeros(n_q, bool)
-        head_keep[heads] = True
-        layers.append({"masks": masks, "heads": head_keep,
-                       "n_q_kept": len(heads), "ff_kept": int(len(chans)),
-                       "ff": int(ff)})
-    return {"layers": layers, "problems": problems}
-
-
 # ------------------------------------------------------------ reference
 
 def _unpack(bits: np.ndarray, shape) -> jax.Array:
     n = int(np.prod(shape))
     return jnp.unpackbits(jnp.asarray(bits))[:n].reshape(shape).astype(bool)
-
-
-def layer_weights(block: dict, sel: dict) -> dict:
-    """One layer's float32 reference weights: dense weights where the
-    selection kept them, zero elsewhere (a removed head or channel is
-    all zero, which adds exactly nothing)."""
-    out = {}
-    for group, names in (("attn", ("q", "k", "v", "o")),
-                         ("mlp", ("gate", "up", "down"))):
-        for name in names:
-            w = block[group][name]
-            m = _unpack(sel["masks"][name], w.shape)
-            out[name] = jnp.where(m, w.astype(jnp.float32), 0.0)
-    heads = jnp.asarray(sel["heads"])
-    for name in ("q_bias", "k_bias", "v_bias"):
-        if name in block["attn"]:
-            b = block["attn"][name].astype(jnp.float32)
-            out[name] = jnp.where(heads[:, None], b, 0.0) \
-                if name == "q_bias" else b
-    out["norm1"] = block["norm1"]["scale"].astype(jnp.float32)
-    out["norm2"] = block["norm2"]["scale"].astype(jnp.float32)
-    return out
 
 
 def _rms(x, scale, eps):
@@ -236,43 +150,33 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def make_layer_fn(theta: float, eps: float, q_block: int = 512):
-    """``fn(w, x) -> x``: one decoder layer over one sequence ``x``
-    (S, d), causal from position 0, queries in blocks of ``q_block``."""
-
-    def layer(w, x):
-        S = x.shape[0]
-        h = _rms(x, w["norm1"], eps)
-        q = jnp.tensordot(h, w["q"], axes=1)
-        k = jnp.tensordot(h, w["k"], axes=1)
-        v = jnp.tensordot(h, w["v"], axes=1)
-        if "q_bias" in w:
-            q, k, v = q + w["q_bias"], k + w["k_bias"], v + w["v_bias"]
-        pos = jnp.arange(S)
-        q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-        n_q, n_kv, hd = q.shape[1], k.shape[1], q.shape[2]
-        g = n_q // n_kv
-        k = jnp.repeat(k, g, axis=1)
-        v = jnp.repeat(v, g, axis=1)
-        outs = []
-        for b0 in range(0, S, q_block):
-            b1 = min(S, b0 + q_block)
-            s = jnp.einsum("qhe,khe->hqk", q[b0:b1], k[:b1]) / math.sqrt(hd)
-            causal = pos[:b1][None, :] <= pos[b0:b1][:, None]
-            s = jnp.where(causal[None], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            outs.append(jnp.einsum("hqk,khe->qhe", p, v[:b1]))
-        att = jnp.concatenate(outs, 0).reshape(S, n_q * hd)
-        x = x + jnp.tensordot(att, w["o"].reshape(n_q * hd, -1), axes=1)
-        h = _rms(x, w["norm2"], eps)
-        act = jax.nn.silu(jnp.tensordot(h, w["gate"], axes=1)) \
-            * jnp.tensordot(h, w["up"], axes=1)
-        return x + jnp.tensordot(act, w["down"], axes=1)
-
-    def run(w, x):
-        with jax.default_matmul_precision("highest"):
-            return layer(w, x)
-    return jax.jit(run)
+def attention(w, h, theta: float, q_block: int = 512):
+    """The attention sublayer over one normed sequence ``h`` (S, d),
+    causal from position 0, queries in blocks of ``q_block``: q/k/v
+    (with their biases where ``w`` has them), rotary positions, grouped
+    queries, and the output projection ``o``."""
+    S = h.shape[0]
+    q = jnp.tensordot(h, w["q"], axes=1)
+    k = jnp.tensordot(h, w["k"], axes=1)
+    v = jnp.tensordot(h, w["v"], axes=1)
+    if "q_bias" in w:
+        q, k, v = q + w["q_bias"], k + w["k_bias"], v + w["v_bias"]
+    pos = jnp.arange(S)
+    q, k = _rope(q, pos, theta), _rope(k, pos, theta)
+    n_q, n_kv, hd = q.shape[1], k.shape[1], q.shape[2]
+    g = n_q // n_kv
+    k = jnp.repeat(k, g, axis=1)
+    v = jnp.repeat(v, g, axis=1)
+    outs = []
+    for b0 in range(0, S, q_block):
+        b1 = min(S, b0 + q_block)
+        s = jnp.einsum("qhe,khe->hqk", q[b0:b1], k[:b1]) / math.sqrt(hd)
+        causal = pos[:b1][None, :] <= pos[b0:b1][:, None]
+        s = jnp.where(causal[None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        outs.append(jnp.einsum("hqk,khe->qhe", p, v[:b1]))
+    att = jnp.concatenate(outs, 0).reshape(S, n_q * hd)
+    return jnp.tensordot(att, w["o"].reshape(n_q * hd, -1), axes=1)
 
 
 def make_head_fn(eps: float, vocab: int):
@@ -287,29 +191,23 @@ def make_head_fn(eps: float, vocab: int):
     return jax.jit(run)
 
 
-def served_gaps(conf_shapes: dict, dense: dict, selection: dict,
-                samples: list, pad_to: int, rows: int = 512) -> list:
-    """Reference gaps of the served tokens. ``samples`` are (prompt,
-    served tokens) pairs; each is run once as prompt + served[:-1],
-    padded to ``pad_to``, layer by layer, in float32. Returns one array
-    per sample, one gap per served token: how far the served token's
-    reference logit lies below the reference's best."""
-    s = conf_shapes
-    layer_fn = make_layer_fn(s["theta"], s["eps"])
-    head_fn = make_head_fn(s["eps"], s["vocab"])
-    table = dense["embed"]["table"]
-    head = (table.T if s["tie"] else dense["lm_head"]["w"]).astype(jnp.float32)
-    norm = dense["final_norm"]["scale"].astype(jnp.float32)
+def embed(table, samples: list, pad_to: int) -> list:
+    """Each (prompt, served tokens) sample as prompt + served[:-1],
+    padded to ``pad_to``, embedded in float32."""
     seqs = []
     for prompt, served in samples:
         ids = np.zeros(pad_to, np.int32)
         full = list(prompt) + list(served[:-1])
         ids[:len(full)] = full
         seqs.append(jnp.take(table, jnp.asarray(ids), 0).astype(jnp.float32))
-    for block, sel in zip(dense["blocks"], selection["layers"]):
-        w = layer_weights(block, sel)
-        seqs = [layer_fn(w, x) for x in seqs]
-        del w
+    return seqs
+
+
+def head_gaps(head_fn, norm, head, seqs: list, samples: list,
+              rows: int = 512) -> list:
+    """One array per sample, one gap per served token: how far the
+    served token's logit lies below the best, read by ``head_fn``
+    (``make_head_fn``) from the last layer's output ``seqs``."""
     out = []
     for x, (prompt, served) in zip(seqs, samples):
         n, start = len(served), len(prompt) - 1

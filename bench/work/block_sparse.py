@@ -3,6 +3,16 @@
 of ``block`` x ``block`` are stored. Operations: a multiply and an add
 per kept weight per row. Bytes: the kept tiles once, the activations
 in once and the result out once."""
+import re
+
+# A Pallas kernel runs as a ``custom-call`` op, and both kernels of the
+# serving path are built from functions named ``_kernel``, so the trace
+# tells them apart by their result: this one returns the (rows, N)
+# product. A name or name scope that says which kernel it is wins where
+# the trace carries one.
+TRACE = re.compile(
+    r"blocksparse_matmul|block_sparse|"
+    r"= \w+\[\d+,\d+\](\{[^}]*\})? custom-call\(")
 
 
 def call(rows: int, K: int, N: int, kept: int, block: int,
@@ -10,15 +20,3 @@ def call(rows: int, K: int, N: int, kept: int, block: int,
     """(operations, bytes) of one call."""
     weights = kept * block * block
     return 2 * rows * weights, weights * w_bytes + rows * (K + N) * x_bytes
-
-
-def step(structure: dict, rows: int) -> tuple:
-    """(operations, bytes) of every block-sparse call in one step of
-    ``rows`` rows: each planned projection of each layer once."""
-    ops = by = 0
-    for layer in structure["layers"]:
-        for K, N, kept in layer["proj"].values():
-            if kept is not None:
-                o, b = call(rows, K, N, kept, structure["block"])
-                ops, by = ops + o, by + b
-    return ops, by

@@ -4,6 +4,14 @@ cached keys and values. For a token at context length ``ctx`` a layer
 reads ``ctx`` keys and values of every kv head, and does ``q . k`` and
 ``p . v`` for every q head: 4 * n_q * head_dim * ctx operations. The
 query and the output are read and written once."""
+import re
+
+# Told apart from the block-sparse kernel's ``custom-call`` by its 4-D
+# (batch, kv heads, group, head_dim) result, where no name or name
+# scope says which kernel it is.
+TRACE = re.compile(
+    r"_decode_jit|paged_attention|"
+    r"= \w+\[\d+,\d+,\d+,\d+\](\{[^}]*\})? custom-call\(")
 
 
 def token(n_q: int, n_kv: int, head_dim: int, ctx: int,

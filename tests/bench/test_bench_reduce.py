@@ -14,6 +14,7 @@ sys.path.insert(0, str(ROOT))
 
 from bench import devtrace, peaks  # noqa: E402
 from bench.layer_metrics import decode_work, prefill_work  # noqa: E402
+from bench.models import dense  # noqa: E402
 from bench.work import block_sparse, model_step, paged_attention  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
@@ -89,7 +90,9 @@ def test_model_step_counts():
         {"n_q": 8, "head_dim": 32,
          "proj": {"q": (256, 256, 2), "o": (256, 256, None)}}]}
     per = 2 * (2 * 128 * 128) + 2 * 256 * 256 + 2 * 256 * 512
-    assert model_step.per_token(st) == per
+    assert dense.ops_per_token(st) == per
+    assert dense.ops_per_context(st) == 4 * 8 * 32
+    st = dict(st, ops_per_token=per, ops_per_context=4 * 8 * 32)
     assert model_step.tokens(st, 3, 30) == 3 * per + 30 * 4 * 8 * 32
 
 

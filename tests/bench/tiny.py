@@ -13,7 +13,7 @@ for p in (str(ROOT), str(ROOT / "src")):
 
 import jax.numpy as jnp  # noqa: E402
 
-from bench import model  # noqa: E402
+from bench import model, models  # noqa: E402
 
 WIDTHS = {
     "phi3-medium-14b": dict(hidden_size=512, num_attention_heads=8,
@@ -75,5 +75,6 @@ def spec(config: str, mix: str, compute: str = "bfloat16") -> dict:
                                        seq_len=32)}})
     traffic = _merge(model.load_json("traffic", mix), LENGTHS[mix])
     return {"name": f"{config}.{mix}", "chips": 1, "conf": conf,
-            "mix": traffic, "cell": dict(CELL, **LIMITS[compute]),
+            "model": models.load(conf), "mix": traffic,
+            "cell": dict(CELL, **LIMITS[compute]),
             "end_to_end": e2e, "per_layer": per_layer}
