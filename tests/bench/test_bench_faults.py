@@ -2,8 +2,9 @@
 broken underneath: a CPU run of the whole harness (past its look for a
 chip) at small widths with one fault planted in the engine's jitted
 steps, once for each fault a served cell can have, and once with the
-control (the program's int8 kept-tile path) served. A cell on one chip
-has no exchange between chips to leave out.
+control (the program's int8 kept-tile path) served; each for both
+configurations (``qwen2-vl-2b`` adds q/k/v biases and a tied head). A
+cell on one chip has no exchange between chips to leave out.
 
 These runs serve in float32, where a sound run's served tokens are the
 reference's best at every position (gap 0), so the limits can sit far
@@ -19,6 +20,7 @@ import pytest
 import tiny
 from bench import control, harness
 
+CONFIGS = ("phi3-medium-14b", "qwen2-vl-2b")
 
 
 def _state_unchanged(eng):
@@ -54,30 +56,33 @@ def _token_altered(eng):
     eng._first_sample = broken
 
 
-def _run(hooks, seed=5):
-    spec = tiny.spec("phi3-medium-14b", "chat", "float32")
+def _run(config, hooks, seed=5):
+    spec = tiny.spec(config, "chat", "float32")
     return harness.run_cell(spec, seed, 2.0, False, time.monotonic(),
                             cache=False, hooks=hooks,
                             log=lambda *a, **k: None)
 
 
+@pytest.mark.parametrize("config", CONFIGS)
 @pytest.mark.parametrize("fault", [_state_unchanged, _half_batch,
                                    _token_altered],
                          ids=["state_unchanged", "half_batch",
                               "token_altered"])
-def test_fault_makes_the_run_not_correct(fault):
-    out = _run({"engine": fault})
+def test_fault_makes_the_run_not_correct(fault, config):
+    out = _run(config, {"engine": fault})
     assert out["correct"] is False
     gap = out["compared"]["logit_gap_mean"]
     assert gap["value"] > gap["limit"]
 
 
-def test_sound_run_is_correct():
-    out = _run({})
+@pytest.mark.parametrize("config", CONFIGS)
+def test_sound_run_is_correct(config):
+    out = _run(config, {})
     assert out["correct"] is True, out["compared"]
 
 
-def test_control_program_int8_path_is_not_correct():
-    out = _run({"artifact": control.int8_artifact})
+@pytest.mark.parametrize("config", CONFIGS)
+def test_control_program_int8_path_is_not_correct(config):
+    out = _run(config, {"artifact": control.int8_artifact})
     assert out["correct"] is False, out["compared"]
     assert jnp.isfinite(out["compared"]["logit_gap_mean"]["value"])
